@@ -776,8 +776,10 @@ def bench(print_fn=print, smoke: bool = False):
     # residual sums come from the kernel outputs, not grid re-reads.  The
     # kernel computes in fp32, so the bound is fp32 epsilon (~1e-5 after
     # 3 iterations), not the float64 paths' 1e-10.
+    from repro.kernels import ops as kernel_ops
     t0 = time.monotonic()
-    out_f, st_f = run_real("interop-nonblk", block_impl="pallas_interpret")
+    out_f, st_f = run_real("interop-nonblk",
+                           block_impl=kernel_ops.pallas_impl())
     dt = (time.monotonic() - t0) / 3
     err_f = float(np.abs(out_f - ref).max())
     assert err_f < 1e-4, err_f

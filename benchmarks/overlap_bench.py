@@ -79,7 +79,6 @@ from repro.models import inputs
 from repro.runtime import steps
 from repro.runtime.sharding import ShardingPolicy
 from repro.launch.mesh import make_mesh
-from repro.compat import shard_map
 
 # Nominal host-interconnect model for the predicted times (per-message
 # latency, seconds per byte on the wire, combine seconds per byte).
@@ -197,7 +196,7 @@ def bench_hierarchical(reps: int, elems: int) -> dict:
         def f(xl):
             return lowering.allreduce(xl, axes, **kw)
         spec = P(tuple(mesh.axis_names))
-        return jax.jit(shard_map(f, mesh=mesh, in_specs=spec, out_specs=P(),
+        return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=spec, out_specs=P(),
                                  axis_names=set(mesh.axis_names),
                                  check_vma=False))
 
@@ -388,7 +387,7 @@ def bench_lowered_stages(reps: int, elems: int) -> dict:
         def f(xl):
             return lowering.allreduce(xl, ("data",), algorithm="ring",
                                       **kw)
-        return jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+        return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                                  out_specs=P(), axis_names={"data"},
                                  check_vma=False))
 
@@ -424,7 +423,7 @@ def bench_inter(reps: int, elems: int) -> dict:
 
         def f(xl):
             return lowering._butterfly_allreduce(xl, "pod", n_pods)
-        sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
+        sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
                                out_specs=P("pod"),
                                axis_names={"pod", "data"},
                                check_vma=False))

@@ -75,7 +75,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..kernels import ops as kernel_ops
 from . import schedule as schedule_ir
 from .options import CollectiveOptions, renamed_kwarg
@@ -123,7 +122,7 @@ def _two_axes(axis_name: Axes) -> Tuple[str, str]:
 
 
 def _check_world(sched: Schedule, axis_name: str) -> None:
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if sched.n != n:
         raise ValueError(f"schedule is for {sched.n} ranks but axis "
                          f"{axis_name!r} has {n} shards")
@@ -183,12 +182,12 @@ def allreduce(x: jax.Array, axes: Axes, *,
             raise ValueError("hierarchical allreduce fixes the composed "
                              "schedule; drop segments=")
         inter_axis, intra_axis = _two_axes(axes)
-        sched = schedule_ir.build_hierarchical(axis_size(intra_axis),
-                                               axis_size(inter_axis))
+        sched = schedule_ir.build_hierarchical(lax.axis_size(intra_axis),
+                                               lax.axis_size(inter_axis))
     if sched is None:
         axis = _single_axis(axes, f"allreduce[{algorithm}]")
-        sched = schedule_ir.build("allreduce", algorithm, axis_size(axis),
-                                  segments=segments)
+        sched = schedule_ir.build("allreduce", algorithm,
+                                  lax.axis_size(axis), segments=segments)
     return lower_allreduce(sched, x, axes, stage_impl=stage_impl,
                            stage_wire=stage_wire)
 
@@ -353,11 +352,11 @@ def _hierarchical_allreduce(sched: Schedule, x: jax.Array, axes: Axes,
     inter_axis, intra_axis = _two_axes(axes)
     sizes = dict(sched.axes)
     n_e, n_i = sizes["inter"], sizes["intra"]
-    if axis_size(inter_axis) != n_e or axis_size(intra_axis) != n_i:
+    if (lax.axis_size(inter_axis), lax.axis_size(intra_axis)) != (n_e, n_i):
         raise ValueError(
             f"schedule is for an (inter={n_e}) × (intra={n_i}) grid but "
             f"axes ({inter_axis!r}, {intra_axis!r}) have sizes "
-            f"({axis_size(inter_axis)}, {axis_size(intra_axis)})")
+            f"({lax.axis_size(inter_axis)}, {lax.axis_size(intra_axis)})")
     if n_e * n_i == 1:
         return x
     orig_shape, orig_dtype = x.shape, x.dtype

@@ -45,7 +45,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
 from . import lowering
 from . import schedule as schedule_ir
 from .options import CollectiveOptions, renamed_kwarg
@@ -181,7 +180,7 @@ def sync_grads(grads, *, axes, mode: str = "bucketed",
             assert len(axis_arg) == 1, "int8 path: single reduction axis"
             return quantized_psum_mean(x.astype(jnp.float32),
                                        axis_arg[0]) * \
-                axis_size(axis_arg[0])  # sync_grads divides later
+                jax.lax.axis_size(axis_arg[0])  # sync_grads divides later
         if compress == "bf16":
             x = x.astype(jnp.bfloat16)
         x = lowering.allreduce(x, axis_arg, algorithm=algorithm,
@@ -246,7 +245,7 @@ def sync_grads(grads, *, axes, mode: str = "bucketed",
         # DP world size is static inside shard_map — no collective needed.
         ws = 1.0
         for a in axis_arg:
-            ws *= axis_size(a)
+            ws *= jax.lax.axis_size(a)
         out = [o / ws for o in out]
     return treedef.unflatten([o.astype(l.dtype)
                               for o, l in zip(out, leaves)])
@@ -267,7 +266,7 @@ def quantized_psum_mean(x: jax.Array, axis: str) -> jax.Array:
     gradients under Adam's normalisation; see EXPERIMENTS.md).
     Must run inside shard_map manual over ``axis``.
     """
-    world = axis_size(axis)
+    world = jax.lax.axis_size(axis)
     n = x.size
     pad = (-n) % world
     if pad:
@@ -307,7 +306,7 @@ def halo_exchange_rows(x, axis_name: str, *, width: int = 1
     one ppermute per direction; boundary ranks have no permutation pair,
     so their halos arrive as ppermute's zeros.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     sched = schedule_ir.build_neighbor(lowering.chain_topology(n))
     got = lowering.lower_neighbor(
         sched, {(0, 1): x[-width:], (0, -1): x[:width]}, axis_name)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
@@ -77,7 +76,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         mask = (jax.lax.axis_index(stage_axis) == S - 1).astype(outbuf.dtype)
         return jax.lax.psum(outbuf * mask, stage_axis)
 
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(stage_axis), P()), out_specs=P(),
         axis_names={stage_axis}, check_vma=False)

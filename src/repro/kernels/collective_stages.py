@@ -190,7 +190,9 @@ def _gs_kernel(b_ref, t_ref, l_ref, btm_ref, r_ref,
     right = jnp.concatenate([b[:, 1:], r_ref[...]], axis=1)
     new = 0.25 * (up + down + left + right)
     o_ref[...] = new.astype(o_ref.dtype)
-    res_ref[0, 0] = jnp.sum(jnp.abs(new - b))
+    # (1, 1) vector tile: a scalar store into VMEM does not lower.
+    res_ref[...] = jnp.sum(jnp.sum(jnp.abs(new - b), axis=1, keepdims=True),
+                           axis=0, keepdims=True)
     te_ref[...] = new[:1, :].astype(te_ref.dtype)
     be_ref[...] = new[-1:, :].astype(be_ref.dtype)
     le_ref[...] = new[:, :1].astype(le_ref.dtype)

@@ -56,9 +56,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(run)
     def _tile():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)            # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bk, d)
+        q = q_ref[...].astype(jnp.float32)                    # (bq, d)
+        k = k_ref[...].astype(jnp.float32)                    # (bk, d)
+        v = v_ref[...].astype(jnp.float32)                    # (bk, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                         # (bq, bk)
@@ -74,16 +74,16 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             mask &= k_pos > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]                                   # (bq,)
+        m_prev = m_ref[...]                                   # (bq, 1)
         l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=-1)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         # Guard fully-masked rows (keep exp(NEG_INF - NEG_INF) at 0).
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -92,14 +92,20 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False) -> jax.Array:
-    """Tiled attention.  q: (B, S, H, D); k/v: (B, T, Hkv, D)."""
+    """Tiled attention.  q: (B, S, H, D); k/v: (B, T, Hkv, D).
+
+    The kernel runs heads-major: q/k/v are transposed to (B, H, S, D) so
+    every block is a (rows, D) tile whose last two dims meet the TPU
+    tiling rule (rows a multiple of 8, D the full head dim); the batch
+    and head block dims are squeezed.
+    """
     B, S, H, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     assert H % Hkv == 0
@@ -114,26 +120,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _attn_kernel, scale=1.0 / (D ** 0.5), causal=causal, window=window,
         block_q=block_q, block_k=block_k, n_kblocks=n_kblocks)
 
-    return pl.pallas_call(
+    qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
+    q_spec = pl.BlockSpec((None, None, block_q, D),
+                          lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, D),
+                           lambda b, h, iq, ik, rep=rep: (b, h // rep, ik, 0))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, iq, ik, rep=rep: (b, ik, h // rep, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, iq, ik, rep=rep: (b, ik, h // rep, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         scratch_shapes=[
-            # fp32 accumulator + online-softmax stats in VMEM, persistent
-            # across the (innermost, sequential) k-block grid dimension.
+            # fp32 accumulator + online-softmax stats (one column per
+            # query row) in VMEM, persistent across the (innermost,
+            # sequential) k-block grid dimension.
             pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(qh, kh, vh)
+    return jnp.swapaxes(out, 1, 2)

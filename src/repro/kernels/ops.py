@@ -8,6 +8,11 @@ forced with ``impl=``:
 * ``impl="pallas_interpret"``  — the TPU kernel body, interpreted (CPU)
 * ``impl="ref"``               — the jnp oracle
 * ``impl=None``                — auto: pallas on TPU else ref
+
+On a TPU backend ``impl=None`` never falls back: the compiled kernel
+runs, or its compile error surfaces.  The kernels keep the callers'
+``(B, S, H, D)`` layout; each kernel module's wrapper moves heads in
+front of the sequence for the TPU tiling rule.
 """
 
 from __future__ import annotations
@@ -29,10 +34,21 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+IMPLS = ("pallas", "pallas_interpret", "ref")
+
+
 def _resolve(impl: Optional[str]) -> str:
     if impl is None:
         return "pallas" if _on_tpu() else "ref"
+    if impl not in IMPLS:
+        raise ValueError(f"unknown kernel impl {impl!r}; one of {IMPLS}")
     return impl
+
+
+def pallas_impl() -> str:
+    """The Pallas kernel as this backend runs it: compiled on a TPU,
+    interpreted elsewhere (for callers that must take the kernel path)."""
+    return "pallas" if _on_tpu() else "pallas_interpret"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,

@@ -11,7 +11,6 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..compat import mesh_axis_kwargs
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,17 +19,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     Single pod: (16, 16) over ("data", "model") — 256 chips.
     Multi-pod:  (2, 16, 16) over ("pod", "data", "model") — 512 chips.
     """
-    import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """A custom mesh (tests, PP demos, elastic restore targets)."""
     import jax
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **mesh_axis_kwargs(len(shape)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def local_mesh(data: Optional[int] = None, model: int = 1):

@@ -28,6 +28,7 @@ from ..models import inputs as model_inputs
 from ..runtime import steps
 from ..runtime.sharding import ShardingPolicy, batch_shardings
 from . import mesh as meshlib
+from .cache import enable_compile_cache
 
 
 def parse_mesh(spec: Optional[str]):
@@ -59,6 +60,7 @@ def main(argv=None) -> int:
     p.add_argument("--dtype", default=None)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.smoke(args.arch) if args.scale == "smoke" \
         else configs.get(args.arch)
     if args.dtype:

@@ -76,6 +76,10 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
+    # Pallas kernel dispatch for the sequence-mixing kernels (``impl=`` of
+    # repro.kernels.ops): None = the compiled kernel on a TPU, the jnp
+    # oracle elsewhere; "ref" is the reference a chip run compares with.
+    kernel_impl: Optional[str] = None
 
     # citation
     source: str = ""

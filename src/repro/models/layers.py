@@ -124,7 +124,7 @@ def attn_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
                               kv_valid_len=valid, q_offset=offs)
     else:
         o = ops.flash_attention(q, k, v, causal=cfg.causal, window=window,
-                                constrain=constrain)
+                                impl=cfg.kernel_impl, constrain=constrain)
         if make_cache:
             new_cache = {"k": k, "v": v}
     y = o.reshape(B, S, H * dh) @ p["wo"]
@@ -347,7 +347,8 @@ def mamba2_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
     else:
         init = cache["state"] if cache else None
         y, state = ops.mamba2_ssd(xh, dt.astype(xh.dtype), A, Bp, Cp,
-                                  chunk=cfg.ssm_chunk, init_state=init)
+                                  chunk=cfg.ssm_chunk, init_state=init,
+                                  impl=cfg.kernel_impl)
     y = y + xh * p["D"][None, None, :, None].astype(x.dtype)
     y = y.reshape(B, S, din)
     # gated RMSNorm (Mamba2): norm(y * silu(z))
@@ -419,7 +420,8 @@ def mlstm_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
         y = y[:, None].astype(x.dtype)
     else:
         chunk = min(cfg.ssm_chunk, S)
-        y, state = ops.mlstm_chunked(q, k, v, ig, fg, chunk=chunk, init=init)
+        y, state = ops.mlstm_chunked(q, k, v, ig, fg, chunk=chunk, init=init,
+                                     impl=cfg.kernel_impl)
     y = y.reshape(B, S, din).astype(jnp.float32)
     y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
                           + cfg.norm_eps) * p["gn"]

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import compat, optim
+from .. import optim
 from ..models import model
 from ..models.config import ModelConfig
 from ..launch.mesh import dp_axes
@@ -176,9 +176,9 @@ def build_train_step_manual(cfg: ModelConfig, mesh, policy: ShardingPolicy,
         out_specs = (specs_for_state(abstract_state),
                      {k: replicated for k in
                       ("lr", "grad_norm", "loss", "total_loss")})
-        f = compat.shard_map(step_local, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(D),
-                             check_vma=False)
+        f = jax.shard_map(step_local, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, axis_names=set(D),
+                          check_vma=False)
         # NOTE: no donation here — donating replicated shard_map inputs
         # deadlocks the CPU backend's collective rendezvous (the donated
         # buffer lives on one device; the implicit broadcast and the psum

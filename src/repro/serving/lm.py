@@ -38,6 +38,7 @@ class LMAdapter:
                  prompt_len: int, gen_len: int) -> None:
         self.cfg = cfg
         self.mesh = mesh
+        self.policy = policy
         self.params = params
         self.prompt_len = prompt_len
         self.total_len = prompt_len + gen_len
@@ -62,13 +63,28 @@ class LMAdapter:
         tok, _ = self.decode(req, state, 1)
         jax.block_until_ready(tok)
 
-    def prefill(self, req: Request) -> Tuple[Any, Any]:
-        key = jax.random.PRNGKey(int(req.prompt))
+    def prompt(self, req: Request) -> Any:
+        """The prompt batch drawn from ``req.prompt`` as a seed."""
+        return model_inputs.make_batch(
+            self.cfg, batch=1, seq=self.prompt_len, kind="prefill",
+            key=jax.random.PRNGKey(int(req.prompt)))
+
+    def compiled_prefill(self, req: Request) -> Any:
+        """The compiled prefill program (``as_text()`` shows whether a
+        Pallas kernel, ``tpu_custom_call``, is on the path)."""
         with self.mesh:
-            batch = model_inputs.make_batch(
-                self.cfg, batch=1, seq=self.prompt_len, kind="prefill",
-                key=key)
-            logits, cache = self._prefill(self.params, batch)
+            return self._prefill.lower(self.params,
+                                       self.prompt(req)).compile()
+
+    def prefill_logits(self, req: Request) -> Any:
+        """Last-position prefill logits, shape (1, 1, vocab)."""
+        with self.mesh:
+            logits, _ = self._prefill(self.params, self.prompt(req))
+        return logits
+
+    def prefill(self, req: Request) -> Tuple[Any, Any]:
+        with self.mesh:
+            logits, cache = self._prefill(self.params, self.prompt(req))
             cache = model.pad_cache(self.cfg, cache, self.total_len)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return tok, (cache, tok)

@@ -27,7 +27,6 @@ def test_quantized_reduction_accuracy_and_wire_dtype():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core.overlap import quantized_psum_mean, sync_grads
 
@@ -39,7 +38,7 @@ xs = jax.random.normal(jax.random.PRNGKey(0), (8, n)) * \\
 def f(x_local):
     return quantized_psum_mean(x_local.reshape(-1), "data")
 
-sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                        out_specs=P(), axis_names={"data"},
                        check_vma=False))
 got = np.asarray(sf(xs.reshape(-1)))
@@ -55,7 +54,7 @@ def g(x_local):
     out = sync_grads({"w": x_local}, axes=("data",), mode="fused",
                      compress="bf16")
     return out["w"]
-sg = jax.jit(shard_map(g, mesh=mesh, in_specs=P("data"),
+sg = jax.jit(jax.shard_map(g, mesh=mesh, in_specs=P("data"),
                        out_specs=P(), axis_names={"data"},
                        check_vma=False))
 got_bf = np.asarray(sg(xs.reshape(-1)))
@@ -73,7 +72,6 @@ def test_quantized_psum_error_bound_per_leg():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core.overlap import quantized_psum_mean
 
@@ -84,7 +82,7 @@ xs = jax.random.normal(jax.random.PRNGKey(7), (8, n)) * \\
 
 def f(x_local):
     return quantized_psum_mean(x_local.reshape(-1), "data")
-sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
                        axis_names={"data"}, check_vma=False))
 got = np.asarray(sf(xs.reshape(-1)))
 exact = np.asarray(jnp.mean(xs, axis=0))
@@ -106,7 +104,6 @@ def test_quantized_psum_padding_non_divisible():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core.overlap import quantized_psum_mean
 
@@ -115,7 +112,7 @@ for n in (4097, 1001, 17, 8):          # 8 % 8 == 0 control included
     xs = jax.random.normal(jax.random.PRNGKey(n), (8, n))
     def f(x_local):
         return quantized_psum_mean(x_local.reshape(-1), "data")
-    sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+    sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
                            axis_names={"data"}, check_vma=False))
     got = np.asarray(sf(xs.reshape(-1)))
     exact = np.asarray(jnp.mean(xs, axis=0))
@@ -132,7 +129,6 @@ def test_quantized_psum_round_trip_vs_fp32_psum():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core.overlap import sync_grads
 
@@ -145,7 +141,7 @@ def make(compress):
         out = sync_grads({"w": x_local}, axes=("data",), mode="fused",
                          compress=compress)
         return out["w"]
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                              out_specs=P(), axis_names={"data"},
                              check_vma=False))
 

@@ -34,7 +34,6 @@ def test_lowered_allreduce_matches_psum_and_schedule_structure():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core import lowering
 from repro.core import schedule as schedule_ir
@@ -47,7 +46,7 @@ for alg, seg in (("ring", 1), ("ring", 4), ("doubling", 1)):
     def f(xl):
         return lowering.allreduce(xl.reshape(-1), ("data",),
                                   algorithm=alg, segments=seg)
-    sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+    sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
                            axis_names={"data"}, check_vma=False))
     got = np.asarray(sf(x.reshape(-1)))
     assert np.max(np.abs(got - want)) < 1e-3, (alg, seg)
@@ -60,7 +59,7 @@ for alg, seg in (("ring", 1), ("ring", 4), ("doubling", 1)):
 # native = one fused node (the sync_grads default)
 def g(xl):
     return lowering.allreduce(xl.reshape(-1), ("data",))
-sg = jax.jit(shard_map(g, mesh=mesh, in_specs=P("data"), out_specs=P(),
+sg = jax.jit(jax.shard_map(g, mesh=mesh, in_specs=P("data"), out_specs=P(),
                        axis_names={"data"}, check_vma=False))
 txt = sg.lower(x.reshape(-1)).as_text()
 assert txt.count("all_reduce") == 1 and txt.count("collective_permute") == 0
@@ -73,7 +72,6 @@ def test_lowered_allreduce_non_divisible_payload():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core import lowering
 
@@ -82,7 +80,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (8, 173))   # 173 % 8 != 0
 def f(xl):
     return lowering.allreduce(xl.reshape(-1), ("data",),
                               algorithm="ring", segments=3)
-sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
                        axis_names={"data"}, check_vma=False))
 got = np.asarray(sf(x.reshape(-1)))
 want = np.asarray(jnp.sum(x, axis=0))
@@ -100,7 +98,6 @@ def test_halo_exchange_rows_executes_neighbor_schedule():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core import tac
 from repro.core.collectives import HaloExchange
@@ -111,7 +108,7 @@ g = jax.random.normal(jax.random.PRNGKey(1), (32, 5))    # 8 shards x 4 rows
 def halo(xl):
     t, b = halo_exchange_rows(xl, "data", width=1)
     return jnp.concatenate([t, b], axis=0)
-sh = jax.jit(shard_map(halo, mesh=mesh, in_specs=P("data"),
+sh = jax.jit(jax.shard_map(halo, mesh=mesh, in_specs=P("data"),
                        out_specs=P("data"), axis_names={"data"},
                        check_vma=False))
 out = np.asarray(sh(g))
@@ -141,7 +138,6 @@ def test_sync_grads_explicit_ring_matches_native():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core.overlap import sync_grads
 
@@ -156,7 +152,7 @@ for alg, seg in (("native", 1), ("ring", 1), ("ring", 2)):
                          mode="bucketed", bucket_bytes=1 << 12,
                          algorithm=alg, segments=seg)
         return out["w"], out["b"]
-    sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+    sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                            out_specs=P(), axis_names={"data"},
                            check_vma=False))
     outs[(alg, seg)] = [np.asarray(o) for o in sf(xs.reshape(-1))]
@@ -196,7 +192,6 @@ assert len(_make_buckets(i8, 8 << 10)) == 1
 
 # and sync_grads derives those bytes from the leaves' WIRE dtype
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core.overlap import sync_grads
 mesh = make_mesh((8,), ("data",))
@@ -206,7 +201,7 @@ def lowered(tree, **kw):
     def f(_x):
         return sync_grads(tree, axes=("data",), mode="bucketed",
                           bucket_bytes=8 << 10, **kw)
-    sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+    sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
                            axis_names={"data"}, check_vma=False))
     return sf.lower(jnp.zeros((8,)))
 def n_ar(low):
@@ -238,7 +233,6 @@ def test_hierarchical_lowering_two_axis_counts_and_numerics():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core import lowering, tac
 from repro.core import schedule as schedule_ir
@@ -254,7 +248,7 @@ for inter, intra in ((2, 4), (4, 2)):
 
     def f(xl):
         return lowering.lower_allreduce(sched, xl, ("pod", "data"))
-    sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+    sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
                            out_specs=P(), axis_names={"pod", "data"},
                            check_vma=False))
     got = np.asarray(sf(x))
@@ -263,7 +257,7 @@ for inter, intra in ((2, 4), (4, 2)):
     # psum parity
     def g(xl):
         return lowering.allreduce(xl, ("pod", "data"))
-    sg = jax.jit(shard_map(g, mesh=mesh, in_specs=P(("pod", "data")),
+    sg = jax.jit(jax.shard_map(g, mesh=mesh, in_specs=P(("pod", "data")),
                            out_specs=P(), axis_names={"pod", "data"},
                            check_vma=False))
     np.testing.assert_allclose(got, np.asarray(sg(x)), atol=1e-3)
@@ -300,7 +294,6 @@ def test_hierarchical_lowering_non_pow2_inter_uses_fused_stage():
         [sys.executable, "-c", """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core import lowering
 
@@ -310,7 +303,7 @@ want = np.asarray(x.reshape(6, -1).sum(axis=0))
 def f(xl):
     return lowering.allreduce(xl, ("pod", "data"),
                               algorithm="hierarchical")
-sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
                        out_specs=P(), axis_names={"pod", "data"},
                        check_vma=False))
 assert np.max(np.abs(np.asarray(sf(x)) - want)) < 1e-3
@@ -332,7 +325,6 @@ def test_sync_grads_hierarchical_two_axis():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core.overlap import sync_grads
 
@@ -347,7 +339,7 @@ for hier in (False, True):
                          axes=("pod", "data"), mode="bucketed",
                          bucket_bytes=1 << 12, hierarchical=hier)
         return out["w"], out["b"]
-    sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+    sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
                            out_specs=P(), axis_names={"pod", "data"},
                            check_vma=False))
     outs[hier] = [np.asarray(o) for o in sf(xs)]
@@ -379,7 +371,6 @@ def test_stage_impl_fused_ring_bit_parity_and_wire_counts():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core import lowering
 from repro.core import schedule as schedule_ir
@@ -393,7 +384,7 @@ def lower(alg, seg, stage_impl=None, wire=None):
         return lowering.allreduce(xl.reshape(-1), ("data",), algorithm=alg,
                                   segments=seg, stage_impl=stage_impl,
                                   wire=wire)
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                              out_specs=P(), axis_names={"data"},
                              check_vma=False))
 
@@ -456,7 +447,6 @@ def test_sync_grads_stage_tier_passthrough():
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.core import overlap
 
@@ -467,7 +457,7 @@ def run(**kw):
     def f(gl):
         return overlap.sync_grads(gl, axes=("data",), algorithm="ring",
                                   mean=False, **kw)
-    sf = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+    sf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
                            axis_names={"data"}, check_vma=False))
     return np.asarray(sf(g)["w"])
 

@@ -296,9 +296,6 @@ class ContinuationEngine:
                 inline = True           # bounded queue full: run it here
             else:
                 self._queue.append(rec)
-                if _tr.TRACING:
-                    _REGISTRY.gauge("continuation.queued").set(
-                        len(self._queue))
         if inline:
             self._n_inline.inc()
             self._run(rec)

@@ -48,7 +48,6 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from ..obs import registry as _reg
 from ..obs import trace as _tr
 from . import events as _ev
 from .continuations import ContinuationEngine
@@ -278,9 +277,6 @@ class TaskRuntime:
                 self._ready.appendleft(task)
             else:
                 self._ready.append(task)
-            if _tr.TRACING:
-                _reg.REGISTRY.gauge("runtime.ready_queue").set(
-                    len(self._ready))
             self._cv.notify()
 
     def _spawn_worker_locked(self) -> None:

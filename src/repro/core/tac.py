@@ -38,7 +38,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 
 from ..obs import trace as _tr
-from ..obs.registry import REGISTRY as _REGISTRY
 from .continuations import PushCompletion
 from .events import (current_task, get_current_blocking_context,
                      get_current_event_counter,
@@ -215,14 +214,12 @@ class EventHandle(PushCompletion, AsyncHandle):
             self._t_post = time.monotonic()
             task = current_task()
             self._obs_rank = None if task is None else task.rank
-            _REGISTRY.gauge("tac.inflight_handles").inc()
 
     def _trace_done(self) -> None:
         """Close the in-flight span (first completion only)."""
         t_post = getattr(self, "_t_post", None)
         if t_post is None:
             return
-        _REGISTRY.gauge("tac.inflight_handles").dec()
         _tr.TRACER.span("handle", "inflight", t_post, time.monotonic(),
                         rank=self._obs_rank, kind=type(self).__name__,
                         ok=self.error is None)

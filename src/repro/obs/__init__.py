@@ -36,15 +36,15 @@ from .analysis import (overlap_fraction, per_rank_overlap, straggler_scores,
 from .metrics import MetricSink, TokenRecord, percentile
 from .registry import REGISTRY, Counter, Gauge, Histogram, MetricsRegistry
 from .trace import (CATEGORIES, DEFAULT_CAPACITY, SPAN_SCHEMA, NullTracer,
-                    Tracer, assert_valid_trace, counter_event, export_trace,
-                    get_tracer, instant_event, set_tracer, span_event,
+                    Tracer, assert_valid_trace, export_trace, get_tracer,
+                    instant_event, profile_window, set_tracer, span_event,
                     tracing, validate_trace)
 
 __all__ = [
     # trace
     "Tracer", "NullTracer", "set_tracer", "get_tracer", "tracing",
-    "export_trace", "validate_trace", "assert_valid_trace",
-    "span_event", "instant_event", "counter_event",
+    "profile_window", "export_trace", "validate_trace", "assert_valid_trace",
+    "span_event", "instant_event",
     "CATEGORIES", "SPAN_SCHEMA", "DEFAULT_CAPACITY",
     "TRACING", "TRACER",
     # registry

@@ -14,11 +14,12 @@ replaces that with instruments designed for the emit side:
   the relaxation is only in ordering), which
   ``tests/test_continuations.py`` asserts by reconciling engine totals
   against ground truth after a multi-threaded run.
-* :class:`Gauge` — a lock-protected level (in-flight handles, queue
-  depths); emission sites only touch gauges when tracing/metrics are
-  wanted, so the lock is off the default path.
-* :class:`Histogram` — power-of-two bucketed latencies (dispatch latency,
-  token latency) with exact count/sum/min/max.
+* :class:`Gauge` — a lock-protected level with its high-water mark.
+* :class:`Histogram` — power-of-two bucketed samples with exact
+  count/sum/min/max: the continuation engine's dispatch latency
+  (``continuation.dispatch_latency_s``) and the serving engine's requests
+  in flight at each device dispatch (``serving.inflight_at_dispatch``),
+  both observed only while tracing.
 
 :data:`REGISTRY` is the process-wide registry; engines may also own
 private instruments (the continuation engine pre-binds its counters as

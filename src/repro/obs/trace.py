@@ -33,12 +33,19 @@ relative to the tracer's epoch (trace-event convention).  ``pid`` carries
 the logical rank (0 when unattributed) and ``tid`` a small per-thread
 index, so Perfetto renders one process row per rank with one track per
 worker thread.
+
+The device profiler keeps a clock of its own.  :func:`profile_window`
+opens a ``jax.profiler.TraceAnnotation`` and records a ``profile/window``
+span over the same interval, so a profile holds one interval known on
+both clocks, and every span of the tracer maps onto the device timeline
+through it.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import json
 import threading
 import time
@@ -47,15 +54,16 @@ from typing import Any, Dict, Iterator, List, Optional
 __all__ = [
     "CATEGORIES", "SPAN_SCHEMA", "DEFAULT_CAPACITY",
     "Tracer", "NullTracer", "TRACER", "TRACING",
-    "set_tracer", "get_tracer", "tracing",
-    "span_event", "instant_event", "counter_event",
+    "set_tracer", "get_tracer", "tracing", "profile_window",
+    "span_event", "instant_event",
     "export_trace", "validate_trace", "assert_valid_trace",
 ]
 
 DEFAULT_CAPACITY = 65536
 
 #: Event categories, one per instrumented layer.
-CATEGORIES = ("task", "handle", "continuation", "collective", "serving")
+CATEGORIES = ("task", "handle", "continuation", "collective", "serving",
+              "host", "profile")
 
 #: The span schema both producers (host tracer, simulator replay) follow:
 #: per category, which complete-span (``ph="X"``) and instant (``ph="i"``)
@@ -77,9 +85,17 @@ SPAN_SCHEMA: Dict[str, Dict[str, tuple]] = {
     # Collective machines / compiled programs: one resolved wait == one
     # round advanced.
     "collective": {"spans": (), "instants": ("round",)},
-    # Serving micro-steps.
-    "serving": {"spans": ("device_step", "detok"),
-                "instants": ("token",)},
+    # Serving: a request's wait from arrival to admission, each
+    # prefill/decode dispatch (``kind``), the prefill cache handoff, and
+    # host detokenisation.
+    "serving": {"spans": ("queued", "device_step", "pad_cache", "detok"),
+                "instants": ()},
+    # The interpreter and JAX under the runtime: garbage collections and
+    # backend compilations.
+    "host": {"spans": ("gc", "compile"), "instants": ()},
+    # An interval marked in the device profiler's trace as well
+    # (profile_window), linking the two clocks.
+    "profile": {"spans": ("window",), "instants": ()},
 }
 
 
@@ -110,14 +126,6 @@ def instant_event(cat: str, name: str, ts_us: float, *,
             "args": args}
 
 
-def counter_event(name: str, value: float, ts_us: float, *,
-                  rank: Optional[int] = None, tid: int = 0) -> Dict[str, Any]:
-    """A counter sample (``ph="C"``) in exported form."""
-    return {"ph": "C", "name": name, "ts": float(ts_us),
-            "pid": 0 if rank is None else int(rank), "tid": int(tid),
-            "args": {name: float(value)}}
-
-
 # ---------------------------------------------------------------------------
 # Tracers.
 # ---------------------------------------------------------------------------
@@ -139,10 +147,6 @@ class NullTracer:
                 rank: Optional[int] = None, **args: Any) -> None:
         pass
 
-    def counter(self, name: str, value: float, *,
-                rank: Optional[int] = None) -> None:
-        pass
-
     def events(self) -> List[Dict[str, Any]]:
         return []
 
@@ -158,6 +162,11 @@ class Tracer:
     lock-free afterwards.  ``events()`` merges all rings sorted by
     timestamp.  Span inputs are ``time.monotonic()`` seconds; storage and
     export are µs relative to the tracer's construction epoch.
+
+    Garbage collections go to a ring of their own (tid 0), written from
+    the ``gc.callbacks`` hook that :func:`set_tracer` installs: a
+    collection can start while its thread holds the tracer's lock, so the
+    hook takes no lock.
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY) -> None:
@@ -166,7 +175,9 @@ class Tracer:
         self.capacity = capacity
         self.epoch = time.monotonic()
         self._lock = threading.Lock()
-        self._rings: List[collections.deque] = []
+        self._gc_ring: collections.deque = collections.deque(maxlen=capacity)
+        self._gc_start = 0.0
+        self._rings: List[collections.deque] = [self._gc_ring]
         self._tls = threading.local()
 
     # -- emit path -----------------------------------------------------------
@@ -200,13 +211,16 @@ class Tracer:
             cat, name, self._us(time.monotonic() if t is None else t),
             rank=rank, tid=self._tls.tid, **args))
 
-    def counter(self, name: str, value: float, *,
-                rank: Optional[int] = None) -> None:
-        """Record a counter sample at the current time."""
-        ring = self._ring()
-        ring.append(counter_event(name, value,
-                                  self._us(time.monotonic()),
-                                  rank=rank, tid=self._tls.tid))
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        """``gc.callbacks`` hook: one ``host/gc`` span per collection
+        (collections do not nest, and each runs under the GIL)."""
+        if phase == "start":
+            self._gc_start = time.monotonic()
+            return
+        self._gc_ring.append(span_event(
+            "host", "gc", self._us(self._gc_start),
+            (time.monotonic() - self._gc_start) * 1e6,
+            generation=info["generation"], collected=info["collected"]))
 
     # -- collection ----------------------------------------------------------
     def events(self) -> List[Dict[str, Any]]:
@@ -231,17 +245,39 @@ TRACER: Any = NullTracer()
 TRACING: bool = False
 
 
+_compile_listener = False
+
+
+def _on_duration(event: str, duration: float, **_: Any) -> None:
+    """``jax.monitoring`` listener: one ``host/compile`` span, ending now,
+    per backend compilation while tracing."""
+    if TRACING and event.endswith("backend_compile_duration"):
+        now = time.monotonic()
+        TRACER.span("host", "compile", now - duration, now, event=event)
+
+
 def set_tracer(tracer: Any) -> Any:
     """Install ``tracer`` as the active tracer; returns the previous one.
 
     Passing a :class:`NullTracer` (or ``None``) disables tracing —
     :data:`TRACING` flips accordingly, so guarded sites go back to their
-    single-attribute-read cost.
+    single-attribute-read cost.  A :class:`Tracer` brings its
+    ``gc.callbacks`` hook (``host/gc``) and takes it away when replaced;
+    the first one installed also registers the ``host/compile`` listener
+    with ``jax.monitoring`` (it records nothing while tracing is off).
     """
-    global TRACER, TRACING
+    global TRACER, TRACING, _compile_listener
     prev = TRACER
+    if isinstance(prev, Tracer) and prev._on_gc in gc.callbacks:
+        gc.callbacks.remove(prev._on_gc)
     TRACER = NullTracer() if tracer is None else tracer
     TRACING = not isinstance(TRACER, NullTracer)
+    if isinstance(TRACER, Tracer):
+        gc.callbacks.append(TRACER._on_gc)
+        if not _compile_listener:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            _compile_listener = True
     return prev
 
 
@@ -265,6 +301,35 @@ def tracing(capacity: int = DEFAULT_CAPACITY,
         yield tr
     finally:
         set_tracer(prev)
+
+
+@contextlib.contextmanager
+def profile_window(name: str) -> Iterator[None]:
+    """Mark an interval on both clocks: a ``jax.profiler.TraceAnnotation``
+    named ``name`` in the device profiler's trace (a no-op where no
+    profile is being taken) and, while tracing, a ``profile/window`` span
+    from the middle of opening the annotation to the middle of closing it.
+
+    ``enter_us`` and ``exit_us`` are how long opening and closing took,
+    another thread's turn on the interpreter lock included: the
+    annotation's start lies within ``enter_us / 2`` of the span's start,
+    and its end within ``exit_us / 2`` of the span's end.
+    """
+    import jax.profiler
+    annotation = jax.profiler.TraceAnnotation(name)
+    t0 = time.monotonic()
+    annotation.__enter__()
+    t1 = time.monotonic()
+    try:
+        yield
+    finally:
+        t2 = time.monotonic()
+        annotation.__exit__(None, None, None)
+        t3 = time.monotonic()
+        if TRACING:
+            TRACER.span("profile", "window", (t0 + t1) / 2, (t2 + t3) / 2,
+                        annotation=name, enter_us=(t1 - t0) * 1e6,
+                        exit_us=(t3 - t2) * 1e6)
 
 
 # ---------------------------------------------------------------------------
@@ -317,7 +382,7 @@ def validate_trace(doc: Any) -> List[str]:
             errors.append(f"{where}: not an object")
             continue
         ph = ev.get("ph")
-        if ph not in ("X", "i", "C"):
+        if ph not in ("X", "i"):
             errors.append(f"{where}: unknown ph {ph!r}")
             continue
         name = ev.get("name")
@@ -331,8 +396,6 @@ def validate_trace(doc: Any) -> List[str]:
                 errors.append(f"{where} ({name}): non-integer {field}")
         if "args" in ev and not isinstance(ev["args"], dict):
             errors.append(f"{where} ({name}): args is not an object")
-        if ph == "C":
-            continue
         cat = ev.get("cat")
         if cat not in SPAN_SCHEMA:
             errors.append(f"{where} ({name}): unknown cat {cat!r}")

@@ -54,6 +54,7 @@ from ..core.executor import TaskError, TaskRuntime
 from ..core.tac import CommRevokedError, RankFailedError
 from ..obs import trace as _tr
 from ..obs.metrics import MetricSink, TokenRecord
+from ..obs.registry import REGISTRY as _REGISTRY
 from .metrics import ServeReport
 from .queue import RequestQueue
 from .request import Request, RequestState
@@ -150,7 +151,11 @@ class ServingEngine:
         path) plus host detok INSIDE the device chain — the artificial
         serialisation of paper §7.1.
         """
-        t0 = time.monotonic() if _tr.TRACING else 0.0
+        t0 = 0.0
+        if _tr.TRACING:
+            t0 = time.monotonic()
+            _REGISTRY.histogram("serving.inflight_at_dispatch",
+                                base=1.0).observe(len(self.active))
         self._tp_allreduce(req, step)
         if step == 0:
             tok, state = self.adapter.prefill(req)
@@ -167,6 +172,7 @@ class ServingEngine:
             if _tr.TRACING:
                 _tr.TRACER.span("serving", "device_step", t0,
                                 time.monotonic(), rid=req.rid, step=step,
+                                kind="prefill" if step == 0 else "decode",
                                 completion=self.completion)
             return tok
         tok = tac.as_handle(tok).wait()     # blocks this worker
@@ -174,6 +180,7 @@ class ServingEngine:
         if _tr.TRACING:
             _tr.TRACER.span("serving", "device_step", t0, time.monotonic(),
                             rid=req.rid, step=step,
+                            kind="prefill" if step == 0 else "decode",
                             completion=self.completion)
         return tok
 
@@ -199,10 +206,6 @@ class ServingEngine:
                 rid=req.rid, step=step,
                 t_submit=req._t_submit[step],    # type: ignore[attr-defined]
                 t_emit=now))
-        if _tr.TRACING:
-            lat = now - req._t_submit[step]  # type: ignore[attr-defined]
-            _tr.TRACER.instant("serving", "token", rid=req.rid, step=step,
-                               latency_s=lat)
 
     def _finish(self, req: Request) -> None:
         """Retire the request — but only if every token actually
@@ -219,9 +222,13 @@ class ServingEngine:
 
     # -- scheduling ---------------------------------------------------------
     def _admit(self, req: Request) -> None:
+        now = time.monotonic()
+        if _tr.TRACING:
+            _tr.TRACER.span("serving", "queued", self._t0 + req.arrival_s,
+                            now, rid=req.rid)
         with self._lock:
             req.to(RequestState.PREFILL)
-            req.admitted_at = time.monotonic() - self._t0
+            req.admitted_at = now - self._t0
             req._t_submit = {}          # type: ignore[attr-defined]
             req._toks = {}              # type: ignore[attr-defined]
             self.active[req.rid] = req

@@ -18,6 +18,7 @@ two completion legs (and re-admissions after eviction) bit-identical.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Tuple
 
 import jax
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import model, inputs as model_inputs
+from ..obs import trace as _tr
 from ..runtime import steps
 from .request import Request
 
@@ -85,7 +87,11 @@ class LMAdapter:
     def prefill(self, req: Request) -> Tuple[Any, Any]:
         with self.mesh:
             logits, cache = self._prefill(self.params, self.prompt(req))
+            t0 = time.monotonic() if _tr.TRACING else 0.0
             cache = model.pad_cache(self.cfg, cache, self.total_len)
+            if _tr.TRACING:
+                _tr.TRACER.span("serving", "pad_cache", t0, time.monotonic(),
+                                rid=req.rid)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return tok, (cache, tok)
 

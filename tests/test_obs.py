@@ -10,6 +10,7 @@ same structure — and the deprecation shims left behind by the
 ``serving.metrics`` → ``repro.obs.metrics`` move.
 """
 
+import gc
 import json
 import threading
 import time
@@ -46,7 +47,6 @@ def test_tracing_disabled_by_default():
     nt = NullTracer()
     nt.span("task", "run", 0.0, 1.0, rank=0)
     nt.instant("task", "submit")
-    nt.counter("x", 1.0)
     assert nt.events() == []
 
 
@@ -116,7 +116,6 @@ def test_export_roundtrip_and_validation(tmp_path):
             label="compute")
     tr.span("handle", "inflight", t0, t0 + 0.02, rank=1, kind="Event")
     tr.instant("continuation", "dispatch")
-    tr.counter("queue", 3.0)
     path = tmp_path / "out.json"
     doc = obs.export_trace(str(path), tracer=tr, extra={"leg": "test"})
     loaded = json.loads(path.read_text())
@@ -135,13 +134,70 @@ def test_validation_catches_schema_violations():
         {"ph": "X", "cat": "task", "name": "run", "ts": 0.0, "dur": -1.0,
          "pid": 0, "tid": 0, "args": {}},                 # negative duration
         {"ph": "Z", "name": "x", "ts": 0.0, "pid": 0, "tid": 0},  # bad ph
+        {"ph": "i", "s": "t", "cat": "serving", "name": "token", "ts": 0.0,
+         "pid": 0, "tid": 0, "args": {}},                 # removed instant
+        {"ph": "C", "name": "queue", "ts": 0.0, "pid": 0, "tid": 0,
+         "args": {"queue": 3.0}},                         # removed counters
     ]
     problems = obs.validate_trace(bad)
-    assert len(problems) == 4
+    assert len(problems) == 6
     with pytest.raises(ValueError):
         obs.assert_valid_trace(bad)
     assert obs.validate_trace({"nope": 1}) \
         == ["document has no 'traceEvents' list"]
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock, garbage collections, compilations
+# ---------------------------------------------------------------------------
+def test_profile_window_records_one_span():
+    with obs.profile_window("untraced"):      # no tracer, no profile
+        pass
+    with obs.tracing() as tr:
+        with obs.profile_window("bench.traced_window"):
+            time.sleep(0.01)
+        wins = [e for e in tr.events() if e["cat"] == "profile"]
+    assert obs.validate_trace(wins) == []
+    assert len(wins) == 1
+    w = wins[0]
+    assert w["name"] == "window"
+    assert w["args"]["annotation"] == "bench.traced_window"
+    assert w["dur"] >= 10_000
+    assert 0 <= w["args"]["enter_us"] + w["args"]["exit_us"] < w["dur"]
+
+
+def test_gc_hook_comes_and_goes_with_the_tracer():
+    enabled = gc.isenabled()
+    gc.disable()                    # no automatic collection in between
+    try:
+        with obs.tracing() as tr:
+            gc.collect()
+            spans = [e for e in tr.events() if e["cat"] == "host"]
+        gc.collect()
+        after = [e for e in tr.events() if e["cat"] == "host"]
+    finally:
+        if enabled:
+            gc.enable()
+    assert len(spans) == 1 and spans[0]["name"] == "gc"
+    assert spans[0]["args"]["generation"] == 2
+    assert spans[0]["args"]["collected"] >= 0
+    assert obs.validate_trace(spans) == []
+    assert after == spans
+    assert tr._on_gc not in gc.callbacks
+
+
+def test_compile_listener_records_backend_compiles():
+    import jax
+    import jax.numpy as jnp
+    k = float(time.monotonic_ns() % 1_000_003)   # a program not yet built
+    with obs.tracing() as tr:
+        jax.jit(lambda x: x * 3.0 + k)(jnp.ones(7)).block_until_ready()
+        spans = [e for e in tr.events()
+                 if e["cat"] == "host" and e["name"] == "compile"]
+    assert spans
+    assert all(e["args"]["event"].endswith("backend_compile_duration")
+               for e in spans)
+    assert obs.validate_trace(spans) == []
 
 
 # ---------------------------------------------------------------------------

@@ -217,6 +217,57 @@ def test_event_leg_pushes_through_continuation_engine():
     assert stats["completions"] >= stats["attached"]
 
 
+@pytest.mark.parametrize("completion", ["event", "blocking"])
+def test_engine_traces_queue_wait_and_slots_in_flight(completion):
+    """One ``serving/queued`` span per request, and one
+    ``serving.inflight_at_dispatch`` observation per device step, never
+    above ``slots``; nothing observed with tracing off."""
+    from repro import obs
+    hist = obs.REGISTRY.histogram("serving.inflight_at_dispatch", base=1.0)
+    with SyntheticAdapter(dev_ms=1.0, host_rounds=1, streams=8) as ad:
+        ad.warmup()
+        hist.reset()
+        with obs.tracing() as tr:
+            ServingEngine(ad, slots=3, completion=completion,
+                          num_workers=3).run(
+                [Request(rid=i, prompt=i, gen_len=4) for i in range(7)])
+        seen = hist.summary()
+        ServingEngine(ad, slots=3, completion=completion,
+                      num_workers=3).run([Request(rid=9, prompt=9,
+                                                  gen_len=2)])
+    evs = tr.events()
+    assert obs.validate_trace(evs) == []
+    queued = [e for e in evs if e["cat"] == "serving"
+              and e["name"] == "queued"]
+    assert sorted(e["args"]["rid"] for e in queued) == list(range(7))
+    steps = [e for e in evs if e["cat"] == "serving"
+             and e["name"] == "device_step"]
+    assert len(steps) == 7 * 4
+    assert all(e["args"]["kind"] == ("prefill" if e["args"]["step"] == 0
+                                     else "decode") for e in steps)
+    assert seen["count"] == len(steps)
+    assert 1 <= seen["min"] and seen["max"] <= 3
+    assert hist.summary() == seen
+
+
+def test_lm_prefill_traces_pad_cache():
+    from repro import configs, obs
+    from repro.launch import mesh as meshlib
+    from repro.models import model
+    from repro.runtime.sharding import ShardingPolicy
+    from repro.serving.lm import LMAdapter
+    cfg = configs.smoke("granite-3-2b")
+    params = jax.jit(lambda k: model.init(cfg, k))(jax.random.PRNGKey(0))
+    ad = LMAdapter(cfg, meshlib.local_mesh(model=1),
+                   ShardingPolicy(fsdp=False, tp=False, sp=False,
+                                  remat=None),
+                   params, prompt_len=8, gen_len=2)
+    with obs.tracing() as tr:
+        ad.prefill(Request(rid=5, prompt=1, gen_len=2))
+    pads = [e for e in tr.events() if e["name"] == "pad_cache"]
+    assert [(e["cat"], e["args"]["rid"]) for e in pads] == [("serving", 5)]
+
+
 def test_percentile_nearest_rank():
     assert percentile([3.0, 1.0, 2.0], 50) == 2.0
     assert percentile([1.0], 99) == 1.0

@@ -3,14 +3,13 @@
 Drives :class:`repro.serving.engine.ServingEngine` over a synthetic
 multi-tenant trace — Poisson arrivals, two priority tenants, mixed
 generation lengths — once per completion leg, on the SAME trace and the
-SAME adapter (``repro.serving.synthetic.SyntheticAdapter``: device
-micro-steps complete asynchronously on a device-queue thread pool, host
-detokenisation is real GIL-releasing work).  The structural claim under
-test is the paper's: the blocking-sentinel leg parks one runtime worker
-inside every device wait, so at most ``--workers`` requests make
-progress regardless of admitted slots, while the event-bound leg
-(``tac.iwait`` → continuation engine) frees the worker at dispatch and
-every in-flight chain advances at device latency.
+SAME adapter (``repro.serving.synthetic.SyntheticAdapter``: prefills
+and decode rounds complete asynchronously, in order, on a device-queue
+thread, host detokenisation is real GIL-releasing work).  The structural
+claim under test is the paper's: the blocking-sentinel leg holds the
+device chain through every device wait and the detokenisation of every
+row, while the event-bound leg (``tac.iwait`` → continuation engine)
+frees it at dispatch and detokenises while the next rounds run.
 
 Hard acceptance (exits non-zero on violation):
 
@@ -23,10 +22,10 @@ Writes ``BENCH_serve.json`` with gated calibration rows
 features + ``overhead_class "serve:<leg>"``) and ``gate_scope:
 ["serve"]`` so ``tools/calibrate.py --gate`` holds this bench
 accountable for exactly its own baseline rows.  Features (per leg, both
-legs identical — only ``measured_s`` differs): ``rounds`` = device
-micro-steps, ``wire_bytes`` = device-occupancy proxy (micro-steps ×
-device latency in µs), ``combine_bytes`` = host detok bytes
-(micro-steps × hash rounds × 64 KiB).
+legs identical — only ``measured_s`` differs): ``rounds`` = tokens
+made (one prefill or one round's row each), ``wire_bytes`` =
+device-occupancy proxy (tokens × device latency in µs),
+``combine_bytes`` = host detok bytes (tokens × hash rounds × 64 KiB).
 
 CSV: name,us_per_call,derived
 """
@@ -34,6 +33,7 @@ CSV: name,us_per_call,derived
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import pathlib
 import sys
@@ -98,7 +98,7 @@ def bench(*, smoke: bool = False, seed: int = 0,
     }
 
     adapter = SyntheticAdapter(dev_ms=dev_ms, host_rounds=host_rounds,
-                               streams=slots)
+                               slots=slots)
     adapter.warmup()
     report = {"requests": n, "slots": slots, "workers": workers,
               "dev_ms": dev_ms, "host_rounds": host_rounds,
@@ -110,6 +110,7 @@ def bench(*, smoke: bool = False, seed: int = 0,
             run_leg(leg, make_trace(4, seed=seed + 1, rate_per_s=1e6,
                                     gen_choices=(2,)),
                     adapter, slots=slots, workers=workers)
+            gc.collect()    # no full collection inside one timed leg
             rep = run_leg(leg, trace, adapter, slots=slots,
                           workers=workers)
             legs[leg] = rep
@@ -161,7 +162,7 @@ def run_traced(trace_path: str, *, seed: int = 0, print_fn=print) -> float:
 
     trace = make_trace(16, seed=seed, rate_per_s=400.0,
                        gen_choices=(4, 8))
-    adapter = SyntheticAdapter(dev_ms=10.0, host_rounds=4, streams=16)
+    adapter = SyntheticAdapter(dev_ms=10.0, host_rounds=4, slots=16)
     adapter.warmup()
     try:
         with obs.tracing(capacity=1 << 18) as tr:

@@ -94,7 +94,7 @@ def serve_phase(*, scale: str = "full", impl: str | None = None,
         f"d_ff={cfg.d_ff} vocab={cfg.vocab} dtype={cfg.dtype}")
     t0 = time.monotonic()
     adapter = serve.build_adapter(cfg, prompt_len=prompt_len, gen=gen,
-                                  seed=seed)
+                                  slots=slots, seed=seed)
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(adapter.params))
     setup_s = time.monotonic() - t0
     log(f"serve: {n_params / 1e9:.3f} B params; init + prefill/decode "
@@ -117,7 +117,7 @@ def serve_phase(*, scale: str = "full", impl: str | None = None,
     probe = serve.make_requests(1, gen=gen, seed=seed)[0]
     ref = LMAdapter(cfg.scaled(kernel_impl="ref"), adapter.mesh,
                     adapter.policy, adapter.params, prompt_len=prompt_len,
-                    gen_len=gen)
+                    gen_len=gen, slots=1)
     got = np.asarray(adapter.prefill_logits(probe), np.float32)
     want = np.asarray(ref.prefill_logits(probe), np.float32)
     check(np.all(np.isfinite(got)), "non-finite Pallas prefill logits")

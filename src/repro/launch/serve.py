@@ -3,10 +3,10 @@
 Thin front-end over :class:`repro.serving.engine.ServingEngine` with the
 real model path (:class:`repro.serving.lm.LMAdapter`): ``--batch``
 requests are admitted through the engine's queue and decoded greedily,
-each prefill/decode micro-step and host detokenisation a runtime task,
-with device completion bound through the AsyncHandle protocol
-(``--completion event``) or synchronised in-task (``--completion
-blocking``, the sentinel baseline).
+each prefill, each decode round over the ``--slots`` rows and each host
+detokenisation a runtime task, with device completion bound through the
+AsyncHandle protocol (``--completion event``) or synchronised in-task
+(``--completion blocking``, the sentinel baseline).
 
   PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
       --batch 4 --prompt-len 32 --gen 32
@@ -35,16 +35,16 @@ def model_config(arch: str, scale: str) -> Any:
     return configs.smoke(arch) if scale == "smoke" else configs.get(arch)
 
 
-def build_adapter(cfg: Any, *, prompt_len: int, gen: int,
+def build_adapter(cfg: Any, *, prompt_len: int, gen: int, slots: int,
                   seed: int = 0) -> LMAdapter:
     """Random weights from ``seed`` on a one-device mesh, behind a
-    compiled and warmed-up :class:`LMAdapter`."""
+    compiled and warmed-up :class:`LMAdapter` of ``slots`` rows."""
     mesh = meshlib.local_mesh(model=1)
     policy = ShardingPolicy(fsdp=False, tp=False, sp=False, remat=None)
     params = jax.jit(functools.partial(model.init, cfg))(
         jax.random.PRNGKey(seed))
     adapter = LMAdapter(cfg, mesh, policy, params,
-                        prompt_len=prompt_len, gen_len=gen)
+                        prompt_len=prompt_len, gen_len=gen, slots=slots)
     adapter.warmup()
     return adapter
 
@@ -84,7 +84,7 @@ def main(argv=None) -> int:
         print(f"[serve] {cfg.name} is encoder-only: no decode step")
         return 0
     adapter = build_adapter(cfg, prompt_len=args.prompt_len, gen=args.gen,
-                            seed=args.seed)
+                            slots=args.slots, seed=args.seed)
     requests = make_requests(args.batch, gen=args.gen, seed=args.seed)
     report = serve(adapter, requests, slots=args.slots,
                    completion=args.completion, workers=args.workers)

@@ -17,9 +17,10 @@ replaces that with instruments designed for the emit side:
 * :class:`Gauge` — a lock-protected level with its high-water mark.
 * :class:`Histogram` — power-of-two bucketed samples with exact
   count/sum/min/max: the continuation engine's dispatch latency
-  (``continuation.dispatch_latency_s``) and the serving engine's requests
-  in flight at each device dispatch (``serving.inflight_at_dispatch``),
-  both observed only while tracing.
+  (``continuation.dispatch_latency_s``), the serving engine's requests
+  in flight at each device dispatch (``serving.inflight_at_dispatch``)
+  and rows decoded at each round dispatch (``serving.rows_per_round``),
+  all observed only while tracing.
 
 :data:`REGISTRY` is the process-wide registry; engines may also own
 private instruments (the continuation engine pre-binds its counters as

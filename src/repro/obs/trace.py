@@ -85,9 +85,9 @@ SPAN_SCHEMA: Dict[str, Dict[str, tuple]] = {
     # Collective machines / compiled programs: one resolved wait == one
     # round advanced.
     "collective": {"spans": (), "instants": ("round",)},
-    # Serving: a request's wait from arrival to admission, each
-    # prefill/decode dispatch (``kind``), the prefill cache handoff, and
-    # host detokenisation.
+    # Serving: a request's wait from arrival to admission, each prefill
+    # or decode-round dispatch (``kind``), the prefill's handoff into its
+    # row, and host detokenisation.
     "serving": {"spans": ("queued", "device_step", "pad_cache", "detok"),
                 "instants": ()},
     # The interpreter and JAX under the runtime: garbage collections and

@@ -233,3 +233,57 @@ def build_decode_step(cfg: ModelConfig, mesh, policy: ShardingPolicy, *,
                      out_shardings=(None, cs),
                      donate_argnums=(1,) if donate else ())
     return jitted, a_cache
+
+
+def build_round_step(cfg: ModelConfig, mesh, policy: ShardingPolicy, *,
+                     slots: int, cache_len: int):
+    """Greedy decode of ``slots`` rows in one program, weights read once.
+
+    The batch-1 decode is mapped over the rows of one stacked cache with
+    the parameters shared, so each row keeps exactly the batch-1
+    arithmetic (per-row position, MoE capacity, recurrent state).  The
+    rows stack on axis 1 of every cache leaf, right after the
+    layer-repeat axis the model scans over: where the scan's batching
+    keeps a mapped axis, so no axis of the whole cache is moved.
+    Returns ``(decode, handoff, abstract_cache)``:
+
+    * ``decode(params, cache, toks, positions) -> (toks, logits, cache)``
+      feeds row r its token ``toks[r]`` at ``positions[r]`` and returns
+      every row's greedy next token and logits; the cache is donated.
+    * ``handoff(cache, toks, prefill_cache, logits, row)
+      -> (cache, toks, first)`` pads a batch-1 prefill's cache to
+      ``cache_len`` and writes it into ``row``, with the prefill's
+      greedy token ``first`` (shape (1,)); the cache is donated.
+    """
+    constrain = Constrainer(mesh, policy, decode=True)
+
+    def row_step(params, cache, tok, pos):
+        logits, cache, _ = model.apply(
+            params, cfg, {"tokens": tok.reshape(1, 1)}, mode="decode",
+            cache=cache, cache_index=pos, constrain=constrain)
+        return logits[0, -1], cache
+
+    def decode(params, cache, toks, positions):
+        logits, cache = jax.vmap(row_step, in_axes=(None, 1, 0, 0),
+                                 out_axes=(0, 1))(params, cache, toks,
+                                                  positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
+
+    def handoff(cache, toks, new, logits, row):
+        new = model.pad_cache(cfg, new, cache_len)
+        cache = jax.tree_util.tree_map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, row, 1),
+            cache, new)
+        first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        return cache, toks.at[row].set(first[0]), first
+
+    row_cache = jax.eval_shape(
+        functools.partial(model.init_cache, cfg, 1, cache_len))
+    a_cache = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[:1] + (slots,) + a.shape[1:],
+                                       a.dtype), row_cache)
+    ps = param_shardings(mesh, abstract_params(cfg), policy)
+    return (jax.jit(decode, in_shardings=(ps, None, None, None),
+                    donate_argnums=(1,)),
+            jax.jit(handoff, donate_argnums=(0,)),
+            a_cache)

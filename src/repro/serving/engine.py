@@ -2,27 +2,31 @@
 
 The serving tentpole: requests flow through a
 :class:`repro.serving.queue.RequestQueue`, an admission/eviction
-scheduler keeps at most ``slots`` of them in flight, and EVERY unit of
-work — each prefill, each decode micro-step, each host detokenisation —
-is one :class:`repro.core.executor.TaskRuntime` task.  Dataflow tokens
-(``Request.chain`` / ``Request.detok_chain``) order one request's
-micro-steps; requests share nothing, so the runtime interleaves them
-freely — continuous batching falls out of task dependencies, there is
-no batching loop.
+scheduler keeps at most ``slots`` of them in flight, each in a row of the
+adapter's decode cache, and EVERY unit of work — each prefill, each
+decode round, each host detokenisation — is one
+:class:`repro.core.executor.TaskRuntime` task.  A decode round advances
+every row of the cache in one device program, so the weights are read
+once per round, not once per token.  Prefills and rounds update the same
+cache, so one dataflow token (the engine's device chain) orders them; a
+request's own token (``Request.chain``) orders its emissions and its
+retirement.  A request admitted while rounds run joins the next round
+after its prefill.
 
 Two completion legs, selected by ``completion=``:
 
-* ``"event"`` (the paper's discipline) — the decode task only
-  *dispatches* device work and completes an
-  :class:`repro.core.tac.EventHandle`; a separate detok task is bound to
-  that event through the unified :class:`repro.core.tac.AsyncHandle`
-  protocol (``tac.wait`` → continuation engine), so host
-  detokenisation overlaps the next decode steps and the device chain
-  never stalls.
-* ``"blocking"`` (the sentinel baseline of paper §7.1) — the decode
-  task synchronises the device result and detokenises inline, chaining
-  host work into the device-step dependency chain exactly like the
-  artificial sentinel dependency the paper removes.
+* ``"event"`` (the paper's discipline) — the prefill or round task only
+  *dispatches* device work and returns, so the device chain is released
+  at dispatch and the round's tokens feed the next round on the device;
+  a wait task binds the tokens' completion through the unified
+  :class:`repro.core.tac.AsyncHandle` protocol (``tac.iwait`` →
+  continuation engine), and a detok task that depends on it emits every
+  row in step order (Fig. 5: consumers declare dependencies).  At most
+  ``ROUNDS_AHEAD`` rounds are dispatched ahead of their tokens.
+* ``"blocking"`` (the sentinel baseline of paper §7.1) — the round task
+  synchronises the device result and detokenises every row inline,
+  chaining host work into the device chain exactly like the artificial
+  sentinel dependency the paper removes.
 
 Both legs emit identical tokens (asserted by
 ``tests/test_serving.py``); ``benchmarks/serve_bench.py`` measures the
@@ -31,7 +35,7 @@ throughput/latency gap.
 Failure handling reuses the ULFM path of :mod:`repro.core.resilience`:
 run the engine stepwise (``sync_every=1``) over a
 :class:`repro.core.tac.CommWorld` and a tensor-parallel allreduce rides
-every micro-step; when a rank dies, the collective surfaces
+every device step; when a rank dies, the collective surfaces
 :class:`~repro.core.tac.RankFailedError` out of ``taskwait``, the
 scheduler evicts every in-flight request back to the queue head,
 revokes + shrinks the world (:func:`repro.core.resilience.recover`),
@@ -59,25 +63,33 @@ from .metrics import ServeReport
 from .queue import RequestQueue
 from .request import Request, RequestState
 
-__all__ = ["ServingEngine"]
+__all__ = ["ServingEngine", "ROUNDS_AHEAD"]
+
+#: decode rounds dispatched ahead of their tokens' emission: enough to
+#: hide the host's turnaround behind the device, few enough that a newly
+#: admitted request joins within that many rounds.
+ROUNDS_AHEAD = 2
 
 
 class ServingEngine:
     """Admission/eviction scheduler + task-graph executor for serving.
 
-    ``adapter`` supplies the model: ``prefill(request) -> (tok, state)``
-    dispatches the prompt pass and returns the first generated token
-    (device value) plus the decode state (KV cache);
-    ``decode(request, state, step) -> (tok, state)`` dispatches one
-    decode micro-step; ``detok(request, step, tok) -> value`` is the
-    host-side detokenisation of a device-complete token.
+    ``adapter`` supplies the model, over a decode cache of
+    ``adapter.slots`` rows: ``prefill(request)`` dispatches the prompt
+    pass into the request's row (``request.slot``) and returns its first
+    token (device value, one element); ``decode_round(row_steps)``
+    dispatches one decode step of every row (``row_steps[r]`` is the
+    step row r decodes, None for an empty row) and returns the rows'
+    tokens; ``detok(request, step, tok)`` is the host-side
+    detokenisation of one device-complete token.
 
-    ``slots`` bounds concurrent in-flight requests; ``priority`` decides
-    preemption — in stepwise mode a queued request preempts (evicts) a
-    strictly lower-priority in-flight one when no slot is free.
+    ``slots`` bounds concurrent in-flight requests and may not exceed
+    the adapter's rows; ``priority`` decides preemption — in stepwise
+    mode a queued request preempts (evicts) a strictly lower-priority
+    in-flight one when no slot is free.
 
     ``world=`` + ``tp_elems>0`` adds a tensor-parallel allreduce over
-    the communicator to every micro-step; with ``sync_every=1`` a rank
+    the communicator to every device step; with ``sync_every=1`` a rank
     failure is recovered ULFM-style (see module docstring).
     """
 
@@ -95,6 +107,9 @@ class ServingEngine:
                              f"one of ['event', 'blocking']")
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
+        if adapter.slots < slots:
+            raise ValueError(f"the adapter decodes {adapter.slots} rows, "
+                             f"fewer than the engine's {slots} slots")
         if sync_every < 0:
             raise ValueError(f"sync_every must be >= 0, got {sync_every}")
         self.adapter = adapter
@@ -103,7 +118,7 @@ class ServingEngine:
         if completion == "event":
             # The event leg NEEDS the TASK_MULTIPLE interoperability
             # level: without it tac.iwait degrades to a blocking wait
-            # inside the decode task body (the legacy-library fallback
+            # inside the wait task body (the legacy-library fallback
             # of §6.3) and the leg silently becomes the sentinel.
             tac.init(tac.TASK_MULTIPLE)
         self.sync_every = sync_every
@@ -126,27 +141,32 @@ class ServingEngine:
         self.admission_log: List[int] = []   # rids, in admission order
         self.eviction_log: List[int] = []    # rids, in eviction order
         self._t0 = 0.0
+        self._free = list(range(slots))      # rows no request holds
+        self._chain = f"device-{id(self)}"   # orders prefills and rounds
+        self._steps = 0                      # device steps submitted
+        self._toks: Dict[int, Any] = {}      # device step -> its tokens
+        self._ahead = 0                      # rounds not yet emitted
+        self._wake = threading.Event()       # a round's tokens emitted
 
     # -- task bodies --------------------------------------------------------
-    def _tp_allreduce(self, req: Request, step: int) -> None:
-        """The tensor-parallel leg of one micro-step (optional)."""
+    def _tp_allreduce(self, k: int) -> None:
+        """The tensor-parallel leg of one device step (optional)."""
         if self._coll is None:
             return
         n = self._coll.world.size
         val = np.ones(self._tp_elems, np.float32)
         self._coll.run_group(
             "allreduce", [{"value": val} for _ in range(n)],
-            op="sum", key=("tp", req.rid, req.incarnation, step))
+            op="sum", key=("tp", k))
 
-    def _device_step(self, req: Request, step: int) -> Any:
-        """One prefill/decode micro-step on the request's device chain.
+    def _device_step(self, k: int, kind: str, entries: List[tuple],
+                     dispatch: Callable[[], Any]) -> None:
+        """One prefill or decode round on the engine's device chain.
 
-        Event leg: dispatch, then ``tac.iwait`` the token's handle — the
-        task body returns immediately and its *dependency release* is
-        bound to device completion through the continuation engine
-        (§6.2), so the detok task's RAW dependency opens exactly when
-        the token is ready and nobody ever blocks a worker.
-
+        ``entries`` are the ``(request, incarnation, step, index)`` of
+        the tokens it makes, ``index`` into the tokens ``dispatch()``
+        returns.  Event leg: dispatch and return — the chain is released
+        at dispatch, and the wait task binds the tokens' completion.
         Blocking-sentinel leg: OS-blocking ``handle.wait()`` (the PMPI
         path) plus host detok INSIDE the device chain — the artificial
         serialisation of paper §7.1.
@@ -156,46 +176,65 @@ class ServingEngine:
             t0 = time.monotonic()
             _REGISTRY.histogram("serving.inflight_at_dispatch",
                                 base=1.0).observe(len(self.active))
-        self._tp_allreduce(req, step)
-        if step == 0:
-            tok, state = self.adapter.prefill(req)
-            with self._lock:
-                req.cache = state
-                if req.state is RequestState.PREFILL:
-                    req.to(RequestState.DECODE)
-        else:
-            tok, state = self.adapter.decode(req, req.cache, step)
-            req.cache = state
-        if self.completion == "event":
-            req._toks[step] = tok       # type: ignore[attr-defined]
-            tac.iwait(tac.as_handle(tok))
-            if _tr.TRACING:
-                _tr.TRACER.span("serving", "device_step", t0,
-                                time.monotonic(), rid=req.rid, step=step,
-                                kind="prefill" if step == 0 else "decode",
-                                completion=self.completion)
-            return tok
-        tok = tac.as_handle(tok).wait()     # blocks this worker
-        self._emit(req, step, tok)
+            if kind == "decode":
+                _REGISTRY.histogram("serving.rows_per_round",
+                                    base=1.0).observe(len(entries))
+        try:
+            self._tp_allreduce(k)
+            toks = dispatch()
+            if self.completion == "event":
+                self._toks[k] = toks
+            else:
+                self._emit_all(entries, tac.as_handle(toks).wait())
+        finally:
+            if self.completion == "blocking" and kind == "decode":
+                self._round_emitted()
         if _tr.TRACING:
             _tr.TRACER.span("serving", "device_step", t0, time.monotonic(),
-                            rid=req.rid, step=step,
-                            kind="prefill" if step == 0 else "decode",
+                            kind=kind, rows=len(entries),
                             completion=self.completion)
+
+    def _prefill(self, req: Request) -> Any:
+        tok = self.adapter.prefill(req)
+        with self._lock:
+            if req.state is RequestState.PREFILL:
+                req.to(RequestState.DECODE)
         return tok
 
-    def _detok_task(self, req: Request, step: int) -> None:
-        """Event-leg host consumer: runs once its RAW dependency on the
-        decode task releases — i.e. once the device value completed —
-        so the token is ready and the host work starts immediately."""
-        tok = req._toks.pop(step, None)     # type: ignore[attr-defined]
-        if tok is None:
-            return      # the producing step failed; nothing to emit
-        t0 = time.monotonic() if _tr.TRACING else 0.0
-        self._emit(req, step, tok)
-        if _tr.TRACING:
-            _tr.TRACER.span("serving", "detok", t0, time.monotonic(),
-                            rid=req.rid, step=step)
+    def _wait_task(self, k: int) -> None:
+        """Event leg: bind this task's release to the tokens' completion
+        (``tac.iwait``), so the detok task's dependency opens exactly
+        when they are ready and nobody ever blocks a worker."""
+        toks = self._toks.get(k)
+        if toks is not None:        # None: the producing step failed
+            tac.iwait(tac.as_handle(toks))
+
+    def _detok_task(self, k: int, kind: str, entries: List[tuple]) -> None:
+        """Event-leg host consumer: runs once the wait task released —
+        i.e. once the device values completed — and emits every row."""
+        try:
+            toks = self._toks.pop(k, None)
+            if toks is None:
+                return      # the producing step failed; nothing to emit
+            t0 = time.monotonic() if _tr.TRACING else 0.0
+            self._emit_all(entries, tac.as_handle(toks).wait())
+            if _tr.TRACING:
+                _tr.TRACER.span("serving", "detok", t0, time.monotonic(),
+                                kind=kind, rows=len(entries))
+        finally:
+            if kind == "decode":
+                self._round_emitted()
+
+    def _round_emitted(self) -> None:
+        with self._lock:
+            self._ahead -= 1
+        self._wake.set()
+
+    def _emit_all(self, entries: List[tuple], toks: Any) -> None:
+        vals = np.asarray(toks)
+        for req, incarnation, step, index in entries:
+            if req.incarnation == incarnation:  # not evicted since
+                self._emit(req, step, vals[index])
 
     def _emit(self, req: Request, step: int, tok: Any) -> None:
         val = self.adapter.detok(req, step, tok)
@@ -208,17 +247,19 @@ class ServingEngine:
                 t_emit=now))
 
     def _finish(self, req: Request) -> None:
-        """Retire the request — but only if every token actually
-        emitted.  A failed micro-step force-releases its dependents
-        (so the graph drains instead of hanging), which means this task
-        can run on an incomplete request: leave it in flight and let
-        the failure sweep of ``_handle_failure`` evict + re-admit it."""
+        """Retire the request and free its row — but only if every token
+        actually emitted.  A failed device step force-releases its
+        dependents (so the graph drains instead of hanging), which means
+        this task can run on an incomplete request: leave it in flight
+        and let the failure sweep of ``_handle_failure`` evict + re-admit
+        it."""
         with self._lock:
             if req.state is RequestState.DECODE \
                     and len(req.tokens) == req.gen_len:
                 req.to(RequestState.DONE)
                 req.finished_at = time.monotonic() - self._t0
                 self.active.pop(req.rid, None)
+                self._free.append(req.slot)
 
     # -- scheduling ---------------------------------------------------------
     def _admit(self, req: Request) -> None:
@@ -230,16 +271,19 @@ class ServingEngine:
             req.to(RequestState.PREFILL)
             req.admitted_at = now - self._t0
             req._t_submit = {}          # type: ignore[attr-defined]
-            req._toks = {}              # type: ignore[attr-defined]
+            req.slot = min(self._free)
+            self._free.remove(req.slot)
             self.active[req.rid] = req
             self.admission_log.append(req.rid)
 
     def _evict(self, req: Request, *, front: bool) -> None:
-        """Drop the request's cache and return it to the queue."""
+        """Free the request's row and return it to the queue."""
         with self._lock:
             req.to(RequestState.EVICTED)
             req.reset_for_requeue()
             self.active.pop(req.rid, None)
+            self._free.append(req.slot)
+            req.slot = None
             self.eviction_log.append(req.rid)
         (self.queue.push_front if front else self.queue.push)(req)
 
@@ -266,35 +310,56 @@ class ServingEngine:
                 return
             self._evict(victim, front=False)
 
-    def _submit_step(self, rt: TaskRuntime, req: Request) -> None:
-        step = req.submitted_steps
-        now = time.monotonic() - self._t0
-        req._t_submit[step] = now       # type: ignore[attr-defined]
-        kind = "prefill" if step == 0 else "decode"
+    def _submit_device(self, rt: TaskRuntime, kind: str,
+                       entries: List[tuple],
+                       dispatch: Callable[[], Any]) -> None:
+        k = self._steps
+        self._steps += 1
+        emits = [req.chain for req, *_ in entries]
         if self.completion == "event":
-            # The decode task WRITES the step's token slot and iwaits the
-            # device handle, so the detok task's READ of that slot opens
-            # at device completion; successive decode steps depend only
-            # on the chain (WAW) — detok never sits on the device chain.
-            slot = (req.chain, "tok", step)
-            rt.submit(self._device_step, req, step,
-                      inout=[req.chain], out=[slot],
-                      name=f"{kind}:{req.rid}@{step}")
-            rt.submit(self._detok_task, req, step, in_=[slot],
-                      inout=[req.detok_chain],
-                      name=f"detok:{req.rid}@{step}")
+            # The device task WRITES the step's tokens; the wait task
+            # reads them and iwaits the device handle, so the detok task's
+            # read of ``ready`` opens at device completion.  Device steps
+            # depend only on the device chain — detok never sits on it.
+            made, ready = (self._chain, "tok", k), (self._chain, "ready", k)
+            rt.submit(self._device_step, k, kind, entries, dispatch,
+                      inout=[self._chain], out=[made], name=f"{kind}:{k}")
+            rt.submit(self._wait_task, k, in_=[made], out=[ready],
+                      name=f"wait:{k}")
+            rt.submit(self._detok_task, k, kind, entries, in_=[ready],
+                      inout=emits, name=f"detok:{k}")
         else:
-            rt.submit(self._device_step, req, step,
-                      inout=[req.chain], name=f"{kind}:{req.rid}@{step}")
-        req.submitted_steps = step + 1
-        if req.submitted_steps == req.gen_len:
-            # finish orders after the device chain AND (event leg) the
-            # detok chain, so the completeness check in _finish sees
-            # every emitted token.
-            chains = [req.chain] if self.completion == "blocking" \
-                else [req.chain, req.detok_chain]
-            rt.submit(self._finish, req, inout=chains,
-                      name=f"finish:{req.rid}")
+            rt.submit(self._device_step, k, kind, entries, dispatch,
+                      inout=[self._chain] + emits, name=f"{kind}:{k}")
+        for req, _, step, _ in entries:
+            if step + 1 == req.gen_len:
+                # finish orders after the request's emissions, so the
+                # completeness check in _finish sees every token.
+                rt.submit(self._finish, req, inout=[req.chain],
+                          name=f"finish:{req.rid}")
+
+    def _submit_prefill(self, rt: TaskRuntime, req: Request) -> None:
+        now = time.monotonic() - self._t0
+        req._t_submit[0] = now          # type: ignore[attr-defined]
+        req.submitted_steps = 1
+        self._submit_device(rt, "prefill", [(req, req.incarnation, 0, 0)],
+                            lambda: self._prefill(req))
+
+    def _submit_round(self, rt: TaskRuntime,
+                      runnable: List[Request]) -> None:
+        now = time.monotonic() - self._t0
+        row_steps: List[Optional[int]] = [None] * self.adapter.slots
+        entries = []
+        for req in sorted(runnable, key=lambda r: r.slot):
+            step = req.submitted_steps
+            req._t_submit[step] = now       # type: ignore[attr-defined]
+            req.submitted_steps = step + 1
+            row_steps[req.slot] = step
+            entries.append((req, req.incarnation, step, req.slot))
+        with self._lock:
+            self._ahead += 1
+        self._submit_device(rt, "decode", entries,
+                            lambda: self.adapter.decode_round(row_steps))
 
     def _handle_failure(self, err: BaseException) -> None:
         """ULFM recovery: evict in-flight requests, shrink, rebuild."""
@@ -338,20 +403,23 @@ class ServingEngine:
                 while len(self.active) < self.slots and self.queue:
                     req = self.queue.pop()
                     self._admit(req)
+                    self._submit_prefill(rt, req)
+                self._wake.clear()
                 with self._lock:
                     runnable = [r for r in self.active.values()
-                                if r.submitted_steps < r.gen_len]
-                for req in sorted(runnable, key=lambda r: r.rid):
-                    self._submit_step(rt, req)
+                                if 0 < r.submitted_steps < r.gen_len]
+                    room = self._ahead < ROUNDS_AHEAD
+                if runnable and room:
+                    self._submit_round(rt, runnable)
                 rounds += 1
                 if self.sync_every and rounds % self.sync_every == 0:
                     try:
                         rt.taskwait()
                     except TaskError as exc:
                         self._handle_failure(exc.error)
-                elif not runnable:
-                    # all steps submitted: give finish tasks air
-                    time.sleep(0.001)
+                elif not (runnable and room):
+                    # nothing to dispatch: wait for a round's tokens
+                    self._wake.wait(0.001)
             rt.taskwait()
         finally:
             if self._ext_runtime is None:

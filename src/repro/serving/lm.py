@@ -1,15 +1,18 @@
 """LM adapter: the real model path behind the serving engine.
 
-Wraps the framework's sharded prefill/decode steps
-(:func:`repro.runtime.steps.build_prefill_step` /
-:func:`~repro.runtime.steps.build_decode_step`) into the adapter
-protocol of :class:`repro.serving.engine.ServingEngine`: each request
-runs batch-1 greedy decoding with its own fixed-size KV cache, handed
-off from prefill via :func:`repro.models.model.pad_cache`.  Device
-values stay un-synchronised — XLA's async dispatch is the in-flight
-operation, and the engine binds completion through
-``tac.as_handle(token)`` (an :class:`repro.core.tac.ArrayHandle`), so
-the event leg overlaps host detokenisation with the next decode steps.
+Wraps the framework's sharded prefill step
+(:func:`repro.runtime.steps.build_prefill_step`) and round step
+(:func:`~repro.runtime.steps.build_round_step`) into the adapter protocol
+of :class:`repro.serving.engine.ServingEngine`.  The adapter owns one
+decode cache of ``slots`` rows, allocated once.  A prefill runs batch 1
+and hands its cache and first token off into the request's row
+(``Request.slot``); one decode round then advances every row in one
+program, reading the weights once for all of them, and its greedy tokens
+stay on the device to feed the next round.  Device values stay
+un-synchronised — XLA's async dispatch is the in-flight operation, and
+the engine binds completion through ``tac.as_handle(tokens)`` (an
+:class:`repro.core.tac.ArrayHandle`), so the event leg overlaps host
+detokenisation with the next rounds.
 
 ``Request.prompt`` is an integer seed; the prompt tokens are drawn with
 :func:`repro.models.inputs.make_batch` under that seed, which keeps the
@@ -19,13 +22,13 @@ two completion legs (and re-admissions after eviction) bit-identical.
 from __future__ import annotations
 
 import time
-from typing import Any, Tuple
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import model, inputs as model_inputs
+from ..models import inputs as model_inputs
 from ..obs import trace as _tr
 from ..runtime import steps
 from .request import Request
@@ -34,16 +37,19 @@ __all__ = ["LMAdapter"]
 
 
 class LMAdapter:
-    """Batch-1 greedy-decode adapter over the sharded step functions."""
+    """Greedy-decode adapter: batch-1 prefill, one round over all rows."""
 
     def __init__(self, cfg: Any, mesh: Any, policy: Any, params: Any, *,
-                 prompt_len: int, gen_len: int) -> None:
+                 prompt_len: int, gen_len: int, slots: int = 4) -> None:
         self.cfg = cfg
         self.mesh = mesh
         self.policy = policy
         self.params = params
         self.prompt_len = prompt_len
         self.total_len = prompt_len + gen_len
+        self.slots = slots
+        #: the last round's logits, (slots, vocab)
+        self.logits: Optional[jax.Array] = None
         with mesh:
             batch = model_inputs.make_batch(
                 cfg, batch=1, seq=prompt_len, kind="prefill",
@@ -51,19 +57,20 @@ class LMAdapter:
             self._prefill = steps.build_prefill_step(
                 cfg, mesh, policy,
                 abstract_batch=jax.eval_shape(lambda: batch))
-            dec_spec = jax.eval_shape(
-                lambda: {"tokens": jnp.zeros((1, 1), jnp.int32)})
-            self._decode, _ = steps.build_decode_step(
-                cfg, mesh, policy, batch=1, cache_len=self.total_len,
-                abstract_batch=dec_spec, donate=False)
+            self._decode, self._handoff, a_cache = steps.build_round_step(
+                cfg, mesh, policy, slots=slots, cache_len=self.total_len)
+            self._cache = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype), a_cache)
+            self._toks = jnp.zeros((slots,), jnp.int32)
 
     # -- the adapter protocol -----------------------------------------------
     def warmup(self) -> None:
-        """Compile prefill + decode outside any timed region."""
+        """Compile prefill, the handoff and the round step."""
         req = Request(rid=-1, prompt=0, gen_len=2)
-        tok, state = self.prefill(req)
-        tok, _ = self.decode(req, state, 1)
-        jax.block_until_ready(tok)
+        req.slot = 0
+        self.prefill(req)
+        jax.block_until_ready(
+            self.decode_round([1] + [None] * (self.slots - 1)))
 
     def prompt(self, req: Request) -> Any:
         """The prompt batch drawn from ``req.prompt`` as a seed."""
@@ -84,26 +91,28 @@ class LMAdapter:
             logits, _ = self._prefill(self.params, self.prompt(req))
         return logits
 
-    def prefill(self, req: Request) -> Tuple[Any, Any]:
+    def prefill(self, req: Request) -> Any:
+        """Prefill ``req`` into its row; returns its first token, (1,)."""
         with self.mesh:
             logits, cache = self._prefill(self.params, self.prompt(req))
             t0 = time.monotonic() if _tr.TRACING else 0.0
-            cache = model.pad_cache(self.cfg, cache, self.total_len)
+            self._cache, self._toks, first = self._handoff(
+                self._cache, self._toks, cache, logits, req.slot)
             if _tr.TRACING:
                 _tr.TRACER.span("serving", "pad_cache", t0, time.monotonic(),
                                 rid=req.rid)
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return tok, (cache, tok)
+        return first
 
-    def decode(self, req: Request, state: Any,
-               step: int) -> Tuple[Any, Any]:
-        cache, prev = state
+    def decode_round(self, row_steps: Sequence[Optional[int]]) -> Any:
+        """Advance every row one token: row r decodes step ``row_steps[r]``
+        (None: an empty row, computed and ignored).  Returns the rows'
+        tokens, (slots,)."""
+        pos = np.array([0 if s is None else self.prompt_len + s - 1
+                        for s in row_steps], np.int32)
         with self.mesh:
-            logits, cache = self._decode(
-                self.params, cache, {"tokens": prev[:, None]},
-                jnp.int32(self.prompt_len + step - 1))
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return tok, (cache, tok)
+            self._toks, self.logits, self._cache = self._decode(
+                self.params, self._cache, self._toks, pos)
+        return self._toks
 
     def detok(self, req: Request, step: int, tok: Any) -> int:
-        return int(np.asarray(tok)[0])
+        return int(tok)

@@ -11,11 +11,13 @@ legal transitions:
       |                  +----evict/failure-------+
       +---- re-admission (EVICTED -> QUEUED, prefill restarts) ----+
 
-Eviction (scheduler preemption or a rank failure surfacing out of
-``taskwait``) drops the request's KV cache and returns it to the queue;
-re-admission restarts it from prefill under a fresh *incarnation* —
-the chain tokens that order its micro-step tasks are incarnation-keyed,
-so tasks of a dead incarnation can never interleave with the retry.
+Admission gives the request a row of the adapter's decode cache
+(``slot``).  Eviction (scheduler preemption or a rank failure surfacing
+out of ``taskwait``) gives the row back and returns the request to the
+queue; re-admission restarts it from prefill under a fresh
+*incarnation* — the chain token that orders its emissions is
+incarnation-keyed, so tasks of a dead incarnation can never interleave
+with the retry.
 """
 
 from __future__ import annotations
@@ -67,9 +69,9 @@ class Request:
 
     # -- scheduler state -----------------------------------------------------
     state: RequestState = RequestState.QUEUED
-    cache: Any = None                   # adapter decode state (KV cache)
+    slot: Optional[int] = None          # row of the adapter's decode cache
     tokens: List[Any] = dataclasses.field(default_factory=list)
-    submitted_steps: int = 0            # decode micro-steps handed to the rt
+    submitted_steps: int = 0            # steps handed to the runtime
     incarnation: int = 0                # bumped on every re-admission
     evictions: int = 0
     admitted_at: Optional[float] = None
@@ -83,9 +85,8 @@ class Request:
         self.state = new
 
     def reset_for_requeue(self) -> None:
-        """EVICTED -> QUEUED: drop the cache, restart from prefill."""
+        """EVICTED -> QUEUED: restart from prefill."""
         self.to(RequestState.QUEUED)
-        self.cache = None
         self.tokens = []
         self.submitted_steps = 0
         self.incarnation += 1
@@ -93,10 +94,5 @@ class Request:
 
     @property
     def chain(self) -> str:
-        """Dependency token ordering this incarnation's device steps."""
+        """Dependency token ordering this incarnation's emissions."""
         return f"req-{self.rid}.{self.incarnation}"
-
-    @property
-    def detok_chain(self) -> str:
-        """Dependency token ordering this incarnation's host detoks."""
-        return f"detok-{self.rid}.{self.incarnation}"

@@ -97,9 +97,11 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
+                    scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False) -> jax.Array:
-    """Tiled attention.  q: (B, S, H, D); k/v: (B, T, Hkv, D).
+    """Tiled attention.  q: (B, S, H, D); k/v: (B, T, Hkv, D).  Scores
+    are scaled by ``scale`` (default ``D^-1/2``).
 
     The kernel runs heads-major: q/k/v are transposed to (B, H, S, D) so
     every block is a (rows, D) tile whose last two dims meet the TPU
@@ -117,7 +119,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     grid = (B, H, S // block_q, n_kblocks)
 
     kernel = functools.partial(
-        _attn_kernel, scale=1.0 / (D ** 0.5), causal=causal, window=window,
+        _attn_kernel, scale=1.0 / (D ** 0.5) if scale is None else scale,
+        causal=causal, window=window,
         block_q=block_q, block_k=block_k, n_kblocks=n_kblocks)
 
     qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))

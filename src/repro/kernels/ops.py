@@ -52,8 +52,10 @@ def pallas_impl() -> str:
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
+                    scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     impl: Optional[str] = None, constrain=None) -> jax.Array:
+    """Scores are scaled by ``scale`` (default ``head_dim^-1/2``)."""
     impl = _resolve(impl)
     if impl == "ref":
         # Flash-style chunked jnp (O(S·chunk) memory) — dense ref.attention
@@ -62,10 +64,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         S, T = q.shape[1], k.shape[1]
         if causal and window is None and S == T and S >= 4096 \
                 and S % 2 == 0:
-            return ref.attention_causal_split(q, k, v, constrain=constrain)
+            return ref.attention_causal_split(q, k, v, constrain=constrain,
+                                              scale=scale)
         return ref.attention_chunked(q, k, v, causal=causal, window=window,
-                                     constrain=constrain)
-    return _flash_pallas(q, k, v, causal=causal, window=window,
+                                     constrain=constrain, scale=scale)
+    return _flash_pallas(q, k, v, causal=causal, window=window, scale=scale,
                          block_q=block_q, block_k=block_k,
                          interpret=(impl == "pallas_interpret"))
 

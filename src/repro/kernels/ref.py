@@ -24,10 +24,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               window: Optional[int] = None,
               softcap: float = 0.0,
               kv_valid_len: Optional[jax.Array] = None,
-              q_offset: Optional[jax.Array] = None) -> jax.Array:
+              q_offset: Optional[jax.Array] = None,
+              scale: Optional[float] = None) -> jax.Array:
     """Reference multi-head attention.
 
     q: (B, S, H, D); k, v: (B, T, Hkv, D) with H % Hkv == 0.
+    Scores are scaled by ``scale`` (default ``D^-1/2``).
     ``kv_valid_len``: (B,) — only cache positions < len attend (decode).
     ``q_offset``: (B,) — absolute position of q[:, 0] (decode: cache index).
     """
@@ -41,7 +43,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if rep > 1:
         kf = jnp.repeat(kf, rep, axis=2)
         vf = jnp.repeat(vf, rep, axis=2)
-    scores = jnp.einsum("bshd,bthd->bhst", qf, kf) / jnp.sqrt(D)
+    if scale is None:
+        scores = jnp.einsum("bshd,bthd->bhst", qf, kf) / jnp.sqrt(D)
+    else:
+        scores = jnp.einsum("bshd,bthd->bhst", qf, kf) * scale
     if softcap > 0.0:
         scores = jnp.tanh(scores / softcap) * softcap
 
@@ -67,7 +72,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool = True, window: Optional[int] = None,
                       chunk: int = 512, constrain=None,
-                      q_offset: int = 0) -> jax.Array:
+                      q_offset: int = 0,
+                      scale: Optional[float] = None) -> jax.Array:
     """Flash-style attention in pure jnp with a flash-style custom VJP.
 
     Forward: ``lax.scan`` over key chunks with online-softmax running
@@ -81,11 +87,14 @@ def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``.attn_stats`` ((B,H,S)) sharding constraints — GSPMD's while-loop
     sharding propagation otherwise REPLICATES the scan carries, which at
     (256,48,4096,128) fp32 is a 24 GiB-per-device bug, not a perf knob.
+    Scores are scaled by ``scale`` (default ``D^-1/2``).
     """
-    return _attn_vjp(q, k, v, causal, window, chunk, constrain, q_offset)
+    return _attn_vjp(q, k, v, causal, window, chunk, constrain, q_offset,
+                     scale)
 
 
-def attention_causal_split(q, k, v, *, chunk: int = 512, constrain=None):
+def attention_causal_split(q, k, v, *, chunk: int = 512, constrain=None,
+                           scale: Optional[float] = None):
     """One-level causal split: the first half of q attends only the first
     half of k/v — removes the fully-masked lower-left quadrant, cutting
     causal attention flops by 25% (and its kernel-tile traffic likewise).
@@ -93,9 +102,10 @@ def attention_causal_split(q, k, v, *, chunk: int = 512, constrain=None):
     B, S, H, D = q.shape
     half = S // 2
     o1 = attention_chunked(q[:, :half], k[:, :half], v[:, :half],
-                           causal=True, chunk=chunk, constrain=constrain)
+                           causal=True, chunk=chunk, constrain=constrain,
+                           scale=scale)
     o2 = attention_chunked(q[:, half:], k, v, causal=True, chunk=chunk,
-                           constrain=constrain, q_offset=half)
+                           constrain=constrain, q_offset=half, scale=scale)
     return jnp.concatenate([o1, o2], axis=1)
 
 
@@ -119,7 +129,7 @@ def _c_stats(constrain, x):
 
 
 def _attn_fwd_impl(q, k, v, causal, window, chunk, constrain=None,
-                   q_offset=0):
+                   q_offset=0, scale=None):
     B, S, H, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     rep = H // Hkv
@@ -133,7 +143,7 @@ def _attn_fwd_impl(q, k, v, causal, window, chunk, constrain=None,
     vc = jnp.moveaxis(v.reshape(B, nc, chunk, Hkv, D), 1, 0)
 
     qf = q.astype(jnp.float32)
-    scale = 1.0 / (D ** 0.5)
+    scale = 1.0 / (D ** 0.5) if scale is None else scale
 
     def body(carry, xs):
         # named_scope tags every op in this body as kernel-resident: on the
@@ -171,21 +181,23 @@ def _attn_fwd_impl(q, k, v, causal, window, chunk, constrain=None,
     return jnp.moveaxis(out, 1, 2).astype(q.dtype), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _attn_vjp(q, k, v, causal, window, chunk, constrain=None, q_offset=0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _attn_vjp(q, k, v, causal, window, chunk, constrain=None, q_offset=0,
+              scale=None):
     out, _ = _attn_fwd_impl(q, k, v, causal, window, chunk, constrain,
-                            q_offset)
+                            q_offset, scale)
     return out
 
 
 def _attn_vjp_fwd(q, k, v, causal, window, chunk, constrain=None,
-                  q_offset=0):
+                  q_offset=0, scale=None):
     out, lse = _attn_fwd_impl(q, k, v, causal, window, chunk, constrain,
-                              q_offset)
+                              q_offset, scale)
     return out, (q, k, v, out, lse)
 
 
-def _attn_vjp_bwd(causal, window, chunk, constrain, q_offset, res, dout):
+def _attn_vjp_bwd(causal, window, chunk, constrain, q_offset, scale, res,
+                  dout):
     """Flash backward: recompute per-chunk probabilities from lse."""
     q, k, v, out, lse = res
     B, S, H, D = q.shape
@@ -203,7 +215,7 @@ def _attn_vjp_bwd(causal, window, chunk, constrain, q_offset, res, dout):
     qf = q.astype(jnp.float32)
     do = dout.astype(jnp.float32)
     of = out.astype(jnp.float32)
-    scale = 1.0 / (D ** 0.5)
+    scale = 1.0 / (D ** 0.5) if scale is None else scale
     # delta[b,s,h] = sum_d dout*out — the softmax-jacobian diagonal term
     delta = jnp.einsum("bshd,bshd->bhs", do, of)       # (B,H,S)
 

@@ -2,8 +2,9 @@
 
 One :class:`ModelConfig` describes any architecture in the assigned pool via
 a *block pattern*: a repeating unit of block kinds (attention, SWA, MLP, MoE,
-Mamba2, sLSTM, mLSTM, shared attention) applied pre-norm with residual
-connections.  `configs/<arch>.py` instantiates the exact published
+Mamba2, sLSTM, mLSTM) applied pre-norm with residual connections, and, for
+Zamba2-style hybrids, a schedule of shared-block invocations over that
+stack.  `configs/<arch>.py` instantiates the exact published
 configurations.
 """
 
@@ -17,7 +18,6 @@ import jax.numpy as jnp
 # Block kinds
 ATTN = "attn"              # global GQA attention
 SWA = "swa"                # sliding-window GQA attention
-SHARED_ATTN = "shared_attn"  # Zamba2-style: one shared weight set, reused
 MLP = "mlp"                # SwiGLU / GeLU MLP
 MOE = "moe"                # top-k mixture of experts
 MAMBA2 = "mamba2"          # state-space dual (SSD) block
@@ -45,6 +45,14 @@ class ModelConfig:
     rope_theta: float = 1e4
     sliding_window: Optional[int] = None
     causal: bool = True              # False for encoder-only architectures
+
+    # Zamba2 hybrid schedule: the layers whose Mamba2 input gains the
+    # output of a shared attention block (invocation i runs block
+    # i % shared_blocks over concat(x, embeddings), with its own LoRA and
+    # output linear of rank adapter_rank).  Empty for every other model.
+    hybrid_layers: Tuple[int, ...] = ()
+    shared_blocks: int = 0
+    adapter_rank: int = 0
 
     # MoE
     n_experts: int = 0
@@ -83,6 +91,14 @@ class ModelConfig:
 
     # citation
     source: str = ""
+
+    def __post_init__(self):
+        # configurations read from JSON carry lists; keep the config
+        # hashable and comparable by turning them into tuples
+        object.__setattr__(self, "pattern",
+                           tuple(tuple(layer) for layer in self.pattern))
+        object.__setattr__(self, "hybrid_layers",
+                           tuple(int(i) for i in self.hybrid_layers))
 
     @property
     def head_dim(self) -> int:

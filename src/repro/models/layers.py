@@ -86,7 +86,7 @@ def attn_init(key, cfg: ModelConfig, d_in: Optional[int] = None) -> Params:
         "wq": _dense_init(ks[0], (d, H * dh), dt),
         "wk": _dense_init(ks[1], (d, Hkv * dh), dt),
         "wv": _dense_init(ks[2], (d, Hkv * dh), dt),
-        "wo": _dense_init(ks[3], (H * dh, d), dt),
+        "wo": _dense_init(ks[3], (H * dh, cfg.d_model), dt),
     }
 
 
@@ -346,7 +346,7 @@ def mamba2_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
         y = y[:, None].astype(x.dtype)
     else:
         init = cache["state"] if cache else None
-        y, state = ops.mamba2_ssd(xh, dt.astype(xh.dtype), A, Bp, Cp,
+        y, state = ops.mamba2_ssd(xh, dt, A, Bp, Cp,
                                   chunk=cfg.ssm_chunk, init_state=init,
                                   impl=cfg.kernel_impl)
     y = y + xh * p["D"][None, None, :, None].astype(x.dtype)
@@ -521,34 +521,86 @@ def slstm_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
 
 
 # ---------------------------------------------------------------------------
-# Zamba2-style shared attention block (one weight set reused across depth)
+# Zamba2 shared transformer block (two weight sets reused across depth)
 # ---------------------------------------------------------------------------
-def shared_attn_init(key, cfg: ModelConfig) -> Params:
-    ks = jax.random.split(key, 4)
+def shared_block_init(key, cfg: ModelConfig) -> Params:
+    """One shared block: attention over the 2d concatenation of the
+    layer's input and the embeddings, then a gated MLP."""
     d, ff = cfg.d_model, cfg.d_ff
+    ks = jax.random.split(key, 3)
     dt = cfg.activation_dtype
     return {
-        "win": _dense_init(ks[0], (2 * d, d), dt),  # concat(x, embeds) proj
-        "norm": norm_init(cfg),
-        "attn": attn_init(ks[1], cfg),
+        "norm": norm_init(cfg, 2 * d),
+        "attn": attn_init(ks[0], cfg, d_in=2 * d),
         "norm2": norm_init(cfg),
-        "mlp": {"wg": _dense_init(ks[2], (d, ff), dt),
-                "wu": _dense_init(ks[3], (d, ff), dt),
-                "wd": _dense_init(jax.random.fold_in(key, 9), (ff, d), dt)},
+        "mlp": {"gate_up": _dense_init(ks[1], (d, 2 * ff), dt),
+                "down": _dense_init(ks[2], (ff, d), dt)},
     }
 
 
-def shared_attn_apply(p: Params, x: jax.Array, x0: jax.Array,
-                      cfg: ModelConfig, *, angles, cache: Cache = None,
-                      cache_index=None, make_cache: bool = False,
-                      constrain=None) -> Tuple[jax.Array, Cache]:
-    h = jnp.concatenate([x, x0], axis=-1) @ p["win"]
-    a_in = norm_apply(p["norm"], h, cfg)
-    a, new_cache = attn_apply(p["attn"], a_in, cfg, angles=angles,
-                              cache=cache, cache_index=cache_index,
-                              make_cache=make_cache, constrain=constrain)
-    h = h + a
-    m_in = norm_apply(p["norm2"], h, cfg)
-    h = h + (jax.nn.silu(m_in @ p["mlp"]["wg"]) * (m_in @ p["mlp"]["wu"])) \
-        @ p["mlp"]["wd"]
-    return h, new_cache
+def shared_adapter_init(key, cfg: ModelConfig) -> Params:
+    """What one invocation of a shared block owns: the rank-r LoRA on the
+    MLP's gate/up projection and the linear that follows the block."""
+    d, ff, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    ks = jax.random.split(key, 3)
+    dt = cfg.activation_dtype
+    return {"lora_a": _dense_init(ks[0], (d, r), dt),
+            "lora_b": _dense_init(ks[1], (r, 2 * ff), dt),
+            "linear": _dense_init(ks[2], (d, d), dt)}
+
+
+def shared_block_apply(p: Params, a: Params, x: jax.Array, x0: jax.Array,
+                       cfg: ModelConfig, *, kv: Cache = None, slot=None,
+                       cache_index=None, constrain=None
+                       ) -> Tuple[jax.Array, Cache]:
+    """One invocation of a Zamba2 shared block, then its linear.
+
+    ``h = rmsnorm(concat(x, x0))``; causal attention from 2d to
+    ``n_heads`` heads of ``head_dim``, scores scaled by
+    ``(head_dim / 2)^-1/2``, no rotary embedding; ``h = rmsnorm(h W_o)``;
+    ``[g, u] = h W_gate_up + h A B`` (the invocation's LoRA);
+    ``gelu(g) * u`` (exact GELU) through ``W_down``; the invocation's
+    linear last.  The block has no residual of its own: its output is
+    added to the input of the Mamba2 layer that follows.
+
+    ``kv`` is the KV cache of every invocation, (invocations, B, T, Hkv,
+    dh), and ``slot`` this invocation's index in it: in decode
+    (``cache_index`` given) the new k/v are written at ``cache_index`` and
+    the block attends over its slot; in prefill the sequence's k/v fill
+    the slot.  Returns the output and the updated ``kv``.
+    """
+    B, S, _ = x.shape
+    dh, H, Hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    scale = (dh / 2) ** -0.5
+    h = norm_apply(p["norm"], jnp.concatenate([x, x0], axis=-1), cfg)
+    w = p["attn"]
+    q = (h @ w["wq"]).reshape(B, S, H, dh)
+    k = (h @ w["wk"]).reshape(B, S, Hkv, dh)
+    v = (h @ w["wv"]).reshape(B, S, Hkv, dh)
+    if constrain is not None:
+        q, k, v = constrain.heads(q), constrain.heads(k), constrain.heads(v)
+    if cache_index is not None:
+        at = (slot, 0, cache_index, 0, 0)
+        kv = {"k": jax.lax.dynamic_update_slice(kv["k"], k[None], at),
+              "v": jax.lax.dynamic_update_slice(kv["v"], v[None], at)}
+        kc, vc = (jax.lax.dynamic_index_in_dim(kv[n], slot, 0, False)
+                  for n in ("k", "v"))
+        o = ops.attention_ref(q, kc, vc, causal=True, scale=scale,
+                              kv_valid_len=jnp.full((B,), cache_index + S,
+                                                    jnp.int32),
+                              q_offset=jnp.full((B,), cache_index,
+                                                jnp.int32))
+    else:
+        o = ops.flash_attention(q, k, v, causal=True, scale=scale,
+                                impl=cfg.kernel_impl, constrain=constrain)
+        if kv is not None:
+            at = (slot, 0, 0, 0, 0)
+            kv = {"k": jax.lax.dynamic_update_slice(kv["k"], k[None], at),
+                  "v": jax.lax.dynamic_update_slice(kv["v"], v[None], at)}
+    h = norm_apply(p["norm2"], o.reshape(B, S, H * dh) @ w["wo"], cfg)
+    gu = h @ p["mlp"]["gate_up"] + (h @ a["lora_a"]) @ a["lora_b"]
+    g, u = jnp.split(gu, 2, axis=-1)
+    m = jax.nn.gelu(g, approximate=False) * u
+    if constrain is not None:
+        m = constrain.ffn(m)
+    return (m @ p["mlp"]["down"]) @ a["linear"], kv

@@ -213,7 +213,12 @@ class MetricsRegistry:
     def counter(self, name: str) -> Counter:
         return self._get(name, Counter)
 
-    def gauge(self, name: str) -> Gauge:
+    def gauge(self, name: str, **labels: str) -> Gauge:
+        """The gauge ``name``; each set of ``labels`` is a gauge of its
+        own, registered as ``name{key=value,...}``."""
+        if labels:
+            name += "{" + ",".join(f"{k}={v}" for k, v in
+                                   sorted(labels.items())) + "}"
         return self._get(name, Gauge)
 
     def histogram(self, name: str, base: float = 1e-6) -> Histogram:

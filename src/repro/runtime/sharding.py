@@ -167,7 +167,8 @@ def param_shardings(mesh, abstract_params, policy: ShardingPolicy):
 
     def assign(path, leaf):
         s = _path_str(path)
-        stacked = "layers/" in s or s.startswith("layers")
+        # stacked over layers, or (Zamba2) over shared blocks/invocations
+        stacked = "layers/" in s or s.startswith(("layers", "shared/"))
         for pat, candidates in rules:
             if re.search(pat, s):
                 break
@@ -216,7 +217,7 @@ def cache_shardings(mesh, abstract_cache, policy: ShardingPolicy):
             spec = [None, D] + [None] * (nd - 2)
             if nd >= 4:
                 s = _path_str(path)
-                if "_attn" in s or "_swa" in s or "_shared" in s:
+                if "_attn" in s or "_swa" in s:
                     # KV cache (R, B, T_time, Hkv, dh): heads over the model
                     # axis when they divide; otherwise shard the TIME dim —
                     # decode attention over a time-sharded cache is the

@@ -14,6 +14,11 @@ the engine binds completion through ``tac.as_handle(tokens)`` (an
 :class:`repro.core.tac.ArrayHandle`), so the event leg overlaps host
 detokenisation with the next rounds.
 
+A row holds every kind of state the model keeps: KV caches, and for the
+Mamba2 hybrids the SSM states and conv tails beside them.  The
+``serving.row_state_bytes`` gauges (label ``kind``: ``ssm``, ``kv``)
+give one row's bytes of each.
+
 ``Request.prompt`` is an integer seed; the prompt tokens are drawn with
 :func:`repro.models.inputs.make_batch` under that seed, which keeps the
 two completion legs (and re-admissions after eviction) bit-identical.
@@ -29,7 +34,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import inputs as model_inputs
+from ..models import model
 from ..obs import trace as _tr
+from ..obs.registry import REGISTRY
 from ..runtime import steps
 from .request import Request
 
@@ -62,6 +69,19 @@ class LMAdapter:
             self._cache = jax.tree_util.tree_map(
                 lambda a: jnp.zeros(a.shape, a.dtype), a_cache)
             self._toks = jnp.zeros((slots,), jnp.int32)
+        #: one row's bytes of state, by kind: ``kv`` (grows with the
+        #: cache length) and ``ssm`` (recurrent state of a fixed size)
+        self.row_state_bytes = {"ssm": 0, "kv": 0}
+        for path, a in jax.tree_util.tree_flatten_with_path(a_cache)[0]:
+            kind = "kv" if model.is_kv(path) else "ssm"
+            self.row_state_bytes[kind] += a.size // slots * a.dtype.itemsize
+        self._publish_row_state()
+
+    def _publish_row_state(self) -> None:
+        """Set the ``serving.row_state_bytes`` gauges; again at every
+        handoff, so that a reset of the registry does not lose them."""
+        for kind, n in self.row_state_bytes.items():
+            REGISTRY.gauge("serving.row_state_bytes", kind=kind).set(n)
 
     # -- the adapter protocol -----------------------------------------------
     def warmup(self) -> None:
@@ -100,7 +120,10 @@ class LMAdapter:
                 self._cache, self._toks, cache, logits, req.slot)
             if _tr.TRACING:
                 _tr.TRACER.span("serving", "pad_cache", t0, time.monotonic(),
-                                rid=req.rid)
+                                rid=req.rid,
+                                ssm_bytes=self.row_state_bytes["ssm"],
+                                kv_bytes=self.row_state_bytes["kv"])
+        self._publish_row_state()
         return first
 
     def decode_round(self, row_steps: Sequence[Optional[int]]) -> Any:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from . import collective_stages as _stages
 from . import ref
+from .decode_attention import decode_attention as _decode_attention
 from .flash_attention import flash_attention as _flash_pallas
 from .mamba2_ssd import mamba2_ssd as _ssd_pallas
 from .moe_gmm import moe_gmm as _gmm_pallas
@@ -190,8 +191,8 @@ def gs_stencil(block, top, left, bottom, right, *,
                               interpret=(impl == "pallas_interpret"))
 
 
-# Pure-jnp layers with no Pallas variant (documented in DESIGN.md):
+# Pure-jnp layers with no Pallas variant:
 mlstm_sequential = ref.mlstm_sequential
 mlstm_decode_step = ref.mlstm_decode_step
 ssd_decode_step = ref.ssd_decode_step
-attention_ref = ref.attention
+decode_attention = _decode_attention

@@ -1,8 +1,11 @@
 """Pure-jnp oracle implementations for every Pallas kernel.
 
 These are the ground truth the kernels are validated against
-(``tests/test_kernels.py`` sweeps shapes/dtypes with ``interpret=True``) and
-the CPU execution path of the models.
+(``tests/test_kernels.py`` sweeps shapes/dtypes with ``interpret=True``),
+what ``ops`` runs for a kernel with ``impl="ref"`` (the CPU default), and
+the layers with no Pallas variant.  The dense :func:`attention` is an
+oracle only: the models decode through ``decode_attention``, which the
+tests compare against it.
 """
 
 from __future__ import annotations

@@ -120,8 +120,9 @@ def attn_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
         new_cache = {"k": kc, "v": vc}
         valid = jnp.full((B,), cache_index + S, jnp.int32)
         offs = jnp.full((B,), cache_index, jnp.int32)
-        o = ops.attention_ref(q, kc, vc, causal=cfg.causal, window=window,
-                              kv_valid_len=valid, q_offset=offs)
+        o = ops.decode_attention(q, kc, vc, causal=cfg.causal,
+                                 window=window, kv_valid_len=valid,
+                                 q_offset=offs)
     else:
         o = ops.flash_attention(q, k, v, causal=cfg.causal, window=window,
                                 impl=cfg.kernel_impl, constrain=constrain)
@@ -585,11 +586,11 @@ def shared_block_apply(p: Params, a: Params, x: jax.Array, x0: jax.Array,
               "v": jax.lax.dynamic_update_slice(kv["v"], v[None], at)}
         kc, vc = (jax.lax.dynamic_index_in_dim(kv[n], slot, 0, False)
                   for n in ("k", "v"))
-        o = ops.attention_ref(q, kc, vc, causal=True, scale=scale,
-                              kv_valid_len=jnp.full((B,), cache_index + S,
-                                                    jnp.int32),
-                              q_offset=jnp.full((B,), cache_index,
-                                                jnp.int32))
+        o = ops.decode_attention(q, kc, vc, causal=True, scale=scale,
+                                 kv_valid_len=jnp.full((B,), cache_index + S,
+                                                       jnp.int32),
+                                 q_offset=jnp.full((B,), cache_index,
+                                                   jnp.int32))
     else:
         o = ops.flash_attention(q, k, v, causal=True, scale=scale,
                                 impl=cfg.kernel_impl, constrain=constrain)

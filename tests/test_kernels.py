@@ -60,6 +60,58 @@ def test_flash_attention_blocks_sweep():
 
 
 # ---------------------------------------------------------------------------
+# grouped-query decode attention
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("vmapped", [False, True])
+@pytest.mark.parametrize("mask", ["full", "window", "partial"])
+@pytest.mark.parametrize("H,Hkv,D,scale", [
+    (32, 8, 64, None),                 # granite
+    (32, 32, 160, (160 / 2) ** -0.5),  # zamba2's shared block
+    (8, 1, 64, None),                  # MQA
+    (48, 8, 128, None),                # mixtral
+])
+def test_decode_attention_vs_ref(H, Hkv, D, scale, mask, vmapped):
+    """One new position over a bf16 cache, as ``attn_apply`` decodes it:
+    the grouped contraction gives the oracle's float32 result, alone and
+    mapped over 4 rows at different positions as the decode round runs
+    it.  ``full``: the last cache position (valid length T); ``window``:
+    a sliding window; ``partial``: valid length below T."""
+    T, rows = 128, 4
+    window = 16 if mask == "window" else None
+    pos = {"full": [T - 1] * rows, "window": [100, 37, 127, 5],
+           "partial": [60, 3, 99, 0]}[mask]
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    # q holds bf16 values in float32, so that both sides return float32
+    q = jax.random.normal(ks[0], (rows, 1, 1, H, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (rows, 1, T, Hkv, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (rows, 1, T, Hkv, D), jnp.bfloat16)
+
+    def row(fn, q, k, v, p):
+        return fn(q, k, v, causal=True, window=window, scale=scale,
+                  kv_valid_len=jnp.full((1,), p + 1, jnp.int32),
+                  q_offset=jnp.full((1,), p, jnp.int32))
+
+    def run(fn, q):
+        if vmapped:
+            return jax.vmap(lambda *a: row(fn, *a))(q, k, v,
+                                                    jnp.asarray(pos))
+        return jnp.stack([row(fn, q[r], k[r], v[r], pos[r])
+                          for r in range(rows)])
+
+    out = run(ops.decode_attention, q.astype(jnp.float32))
+    exp = run(ref.attention, q.astype(jnp.float32))
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               rtol=1e-5, atol=1e-6)
+    # bf16 queries, as the model passes them: a bf16 result within one
+    # rounding of the oracle's
+    out16 = run(ops.decode_attention, q)
+    assert out16.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out16, np.float32),
+                               np.asarray(exp), rtol=2 ** -8, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
 # mamba2 ssd
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -7,6 +7,9 @@ batch-1 arithmetic of :func:`repro.runtime.steps.build_decode_step`
 sliding-window attention with MoE, and the Mamba2 and xLSTM hybrids.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,3 +130,26 @@ def test_engine_over_lm_adapter_matches_batch1():
         want, _ = greedy(ad.prompt(Request(rid=i, prompt=200 + i,
                                            gen_len=g)), g)
         assert outputs["event"][i] == want, i
+
+
+def test_round_reads_cache_without_repeating_it_per_query_head():
+    """The lowered decode round (granite smoke: 4 query heads over 2 KV
+    heads of 16) holds no float32 tensor of the cache's length with
+    slots x T x H x D elements: the cache is not repeated per query head
+    for decode attention."""
+    cfg = configs.smoke("granite-3-2b")
+    slots, T = 4, 64
+    assert cfg.n_heads // cfg.n_kv_heads > 1
+    mesh = meshlib.local_mesh(model=1)
+    policy = ShardingPolicy(fsdp=False, tp=False, sp=False, remat=None)
+    with mesh:
+        decode, _, a_cache = steps.build_round_step(
+            cfg, mesh, policy, slots=slots, cache_len=T)
+        rows = jax.ShapeDtypeStruct((slots,), jnp.int32)
+        text = decode.lower(steps.abstract_params(cfg), a_cache, rows,
+                            rows).as_text()
+    repeated = slots * T * cfg.n_heads * cfg.head_dim
+    found = [s for s in re.findall(r"tensor<([\dx]+)xf32>", text)
+             if T in (dims := [int(d) for d in s.split("x")])
+             and math.prod(dims) == repeated]
+    assert not found, sorted(set(found))

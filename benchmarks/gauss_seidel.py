@@ -95,12 +95,8 @@ def gs_block(block, top, left, bottom, right):
 # ---------------------------------------------------------------------------
 def run_real(version: str, *, n_ranks: int = 4, workers: int = 2,
              nby: int = 2, nbx: int = 2, bs: int = 16, iters: int = 3,
-             seed: int = 0, notify: str = None, block_impl: str = None):
+             seed: int = 0, block_impl: str = None):
     """Returns (final grid, stats).
-
-    ``notify`` picks the runtime's completion-notification backend
-    ("polling" / "continuation"; None = the REPRO_NOTIFY env default) —
-    the end-to-end parity legs run the same benchmark under both.
 
     ``block_impl`` routes the per-block stage through the fused stencil
     kernel (:func:`repro.kernels.ops.gs_stencil`;
@@ -144,7 +140,7 @@ def run_real(version: str, *, n_ranks: int = 4, workers: int = 2,
     edge_cache: Dict = {}  # (gy, gx, it) -> (top, bottom, left, right)
     tac.init(tac.TASK_MULTIPLE if version.startswith("interop")
              else tac.THREAD_MULTIPLE)
-    rt = TaskRuntime(num_workers=workers, notify=notify)
+    rt = TaskRuntime(num_workers=workers)
     rt.start()
 
     def rank_of(gy, gx):
@@ -506,7 +502,7 @@ def run_elastic(ckpt_dir: str, *, n_ranks: int = 4, workers: int = 2,
                 nby: int = 3, nbx: int = 3, bs: int = 8, iters: int = 4,
                 kill_iter: int = None, kill_rank: int = 0,
                 kill_after_ops: int = 1, mode: str = "event",
-                notify: str = None, seed: int = 0):
+                seed: int = 0):
     """Fault-tolerant Gauss–Seidel: the ULFM recovery loop end to end.
 
     Every completed iteration checkpoints the global grid to
@@ -558,7 +554,7 @@ def run_elastic(ckpt_dir: str, *, n_ranks: int = 4, workers: int = 2,
 
     cart, cur_nby, cur_nbx = shape_over(world, n_ranks)
     hx, coll = HaloExchange(cart), Collectives(cart)
-    rt = TaskRuntime(num_workers=workers, notify=notify)
+    rt = TaskRuntime(num_workers=workers)
     rt.start()
     info = {"residuals": {}, "recoveries": []}
 
@@ -581,7 +577,7 @@ def run_elastic(ckpt_dir: str, *, n_ranks: int = 4, workers: int = 2,
                 shrunk = resilience.recover(world)
                 cart, cur_nby, cur_nbx = shape_over(shrunk, shrunk.size)
                 hx, coll = HaloExchange(cart), Collectives(cart)
-                rt = TaskRuntime(num_workers=workers, notify=notify)
+                rt = TaskRuntime(num_workers=workers)
                 rt.start()
                 state, step = checkpoint_lib.restore_checkpoint(
                     ckpt_dir, {"grid": np.empty((NYb * bs, NXb * bs))})
@@ -760,16 +756,14 @@ def bench(print_fn=print, smoke: bool = False):
         for it, val in ref_stats["residuals"].items():
             assert abs(st["residuals"][it] - val) < 1e-9, (v, it)
 
-    # end-to-end notification-backend legs: the same interop run under
-    # the polling engine and the continuation engine must agree with the
-    # pure reference bit for bit (and with each other).
+    # end-to-end interop legs: each must agree with the pure reference
+    # bit for bit.
     for v in ("interop-blk", "interop-nonblk"):
-        for nb in ("polling", "continuation"):
-            t0 = time.monotonic()
-            out, _ = run_real(v, notify=nb)
-            dt = (time.monotonic() - t0) / 3
-            assert float(np.abs(out - ref).max()) < 1e-10, (v, nb)
-            rows.append((f"gs_e2e_{v}_{nb}", dt * 1e6, "notify-leg"))
+        t0 = time.monotonic()
+        out, _ = run_real(v)
+        dt = (time.monotonic() - t0) / 3
+        assert float(np.abs(out - ref).max()) < 1e-10, v
+        rows.append((f"gs_e2e_{v}", dt * 1e6, "e2e"))
 
     # fused-stencil leg (Pallas executor tier): interior update, residual
     # and boundary-pack in ONE kernel pass per block — halo payloads and

@@ -54,12 +54,8 @@ def spectral_step(f: np.ndarray) -> np.ndarray:
 
 def run_real(version: str, *, n_ranks: int = 2, workers: int = 2,
              n_fields: int = 8, n_grid: int = 64, steps: int = 3,
-             seed: int = 0, notify: str = None):
-    """Returns (final fields array, runtime stats).
-
-    ``notify`` picks the runtime's completion-notification backend
-    ("polling" / "continuation"; None = the REPRO_NOTIFY env default).
-    """
+             seed: int = 0):
+    """Returns (final fields array, runtime stats)."""
     assert n_fields % n_ranks == 0 and n_grid % n_ranks == 0
     rng = np.random.default_rng(seed)
     pts = n_grid // n_ranks
@@ -72,7 +68,7 @@ def run_real(version: str, *, n_ranks: int = 2, workers: int = 2,
     exch: Dict = {}   # alltoall results (or event-bound handles)
     tac.init(tac.TASK_MULTIPLE if version.startswith("interop")
              else tac.THREAD_MULTIPLE)
-    rt = TaskRuntime(num_workers=workers, notify=notify)
+    rt = TaskRuntime(num_workers=workers)
     rt.start()
 
     def owner(f: int) -> int:
@@ -265,7 +261,7 @@ def run_elastic(ckpt_dir: str, *, n_ranks: int = 4, workers: int = 2,
                 n_fields: int = 12, n_grid: int = 24, steps: int = 4,
                 kill_step: int = None, kill_rank: int = 0,
                 kill_after_ops: int = 1, mode: str = "event",
-                notify: str = None, seed: int = 0):
+                seed: int = 0):
     """Fault-tolerant IFSKer: checkpoint each step, survive an injected
     rank death mid-transposition, shrink, resume (see
     ``gauss_seidel.run_elastic`` for the recovery protocol).  The axes
@@ -295,7 +291,7 @@ def run_elastic(ckpt_dir: str, *, n_ranks: int = 4, workers: int = 2,
 
     comm = world
     coll = Collectives(world)
-    rt = TaskRuntime(num_workers=workers, notify=notify)
+    rt = TaskRuntime(num_workers=workers)
     rt.start()
     info = {"recoveries": []}
 
@@ -317,7 +313,7 @@ def run_elastic(ckpt_dir: str, *, n_ranks: int = 4, workers: int = 2,
                         f"{n_fields} fields / {n_grid} points do not "
                         f"divide over {shrunk.size} survivors")
                 comm, coll = shrunk, Collectives(shrunk)
-                rt = TaskRuntime(num_workers=workers, notify=notify)
+                rt = TaskRuntime(num_workers=workers)
                 rt.start()
                 state, step = checkpoint_lib.restore_checkpoint(
                     ckpt_dir, {"fields": np.empty((n_fields, n_grid))})
@@ -405,14 +401,13 @@ def bench(print_fn=print):
         err = float(np.abs(out - ref).max())
         assert err < 1e-10, (v, err)
 
-    # end-to-end notification-backend legs: both engines, same numerics.
+    # end-to-end legs: each interop version, same numerics as pure.
     for v in VERSIONS[1:]:
-        for nb in ("polling", "continuation"):
-            t0 = time.monotonic()
-            out, _ = run_real(v, notify=nb)
-            dt = (time.monotonic() - t0) / 3
-            assert float(np.abs(out - ref).max()) < 1e-10, (v, nb)
-            rows.append((f"ifsker_e2e_{v}_{nb}", dt * 1e6, "notify-leg"))
+        t0 = time.monotonic()
+        out, _ = run_real(v)
+        dt = (time.monotonic() - t0) / 3
+        assert float(np.abs(out - ref).max()) < 1e-10, v
+        rows.append((f"ifsker_e2e_{v}", dt * 1e6, "e2e"))
 
     for v in VERSIONS:
         t0 = time.monotonic()

@@ -25,12 +25,10 @@ step, plus the **hierarchical two-axis allreduce** (one
   while the calibrated fit absorbs machine speed and per-call overhead.
 
 Plus the host-runtime **progress leg** (``bench_progress``): the
-polling-vs-continuation notification backends swept over in-flight
-event-bound op counts — the polling registry's per-completion cost is
-linear in the in-flight count while the continuation engine's
-(`repro.core.continuations.ContinuationEngine`) stays flat, asserted
-hard (a notification regression fails the job) and recorded with cost
-features so the calibrated gate covers both backends.
+continuation engine (`repro.core.continuations.ContinuationEngine`)
+swept over in-flight event-bound op counts — its per-completion cost
+stays flat, asserted hard (a notification regression fails the job)
+and recorded with cost features so the calibrated gate covers it.
 
 CSV: name,us_per_call,derived
 """
@@ -70,7 +68,7 @@ from repro import configs, optim
 from repro.kernels import ops as kernel_ops
 from repro.core import lowering
 from repro.core import schedule as schedule_ir
-from repro.core import simulate, tac
+from repro.core import tac
 from repro.core.collectives import (Collectives, CollectiveHandle,
                                     ProgressEngine, _Machine)
 from repro.core.continuations import ContinuationEngine
@@ -538,23 +536,21 @@ def bench_level_a(smoke: bool = False) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Progress-path microbench: polling vs continuation notification
+# Progress-path microbench: continuation notification over an in-flight sweep
 # ---------------------------------------------------------------------------
 IN_FLIGHT_SWEEP = (8, 16, 32, 64)
 PROGRESS_OPS_BUDGET = 20_000    # ~progress ops per timed batch (noise floor)
-TEST_S = DISPATCH_S = 1e-6      # nominal per-progress-op model constants
+DISPATCH_S = 1e-6               # nominal per-dispatch model constant
 
 
-def _progress_setup(backend: str, n: int):
+def _progress_setup(n: int):
     """Arm ``n`` in-flight event-bound machines on a fresh engine.
 
     Returns ``(handles, drain, counters)``: ``drain()`` completes one
     handle per tick and services the engine once per completion (the
-    steady-state shape), ``counters()`` reads the progress-op totals —
-    the polling backend re-tests every pending machine each tick
-    (``n + (n-1) + ... + 1`` tests, linear in the in-flight count per
-    completion) while the continuation backend pays one queue dispatch
-    per completion, flat.
+    steady-state shape), ``counters()`` reads the progress-op totals
+    ``(tests, dispatches)`` — one queue dispatch per completion, flat in
+    the in-flight count, and zero tests.
     """
     handles = [tac.EventHandle() for _ in range(n)]
 
@@ -562,91 +558,71 @@ def _progress_setup(backend: str, n: int):
         res = yield h
         return res
 
-    if backend == "polling":
-        eng = ProgressEngine()
-        service, counters = eng.poll, lambda: (eng.stats["tests"], 0)
-    else:
-        engine = ContinuationEngine()
-        eng = ProgressEngine(notify="continuation", continuations=engine)
-        service = engine.service
-        counters = lambda: (engine.stats["tests"],          # noqa: E731
-                            engine.stats["dispatches"])
+    engine = ContinuationEngine()
+    eng = ProgressEngine(engine)
     for h in handles:
         eng.submit(_Machine(gen(h), CollectiveHandle()))
 
     def drain():
         for i, h in enumerate(handles):
             h.complete(i)
-            service(None)
+            engine.service(None)
         if eng.pending:
             raise SystemExit(f"progress bench: {eng.pending} machines "
-                             f"stuck under {backend}")
+                             f"stuck")
+
+    def counters():
+        return engine.stats["tests"], engine.stats["dispatches"]
     return handles, drain, counters
 
 
 def bench_progress(smoke: bool = False) -> dict:
-    """The polling-vs-continuation leg: progress cost over an in-flight
-    sweep.
+    """The continuation leg: progress cost over an in-flight sweep.
 
     Every row carries linear cost features (``rounds`` = progress ops —
     handle tests + callback dispatches) beside ``measured_s`` so
     ``tools/calibrate.py`` fits the per-op cost and the CI gate covers
-    both backends, plus the nominal
-    `repro.core.simulate.progress_cost` prediction.  The function HARD
-    ASSERTS the scaling claim — continuation progress ops per completion
-    flat (sub-linear in the in-flight count), polling linear — so a
+    it, plus the nominal prediction ``DISPATCH_S × completions``.  The
+    function HARD ASSERTS the scaling claim — progress ops per
+    completion flat (sub-linear in the in-flight count) — so a
     notification regression fails the bench-smoke job outright.
     """
     budget = 4_000 if smoke else PROGRESS_OPS_BUDGET
-    report: dict = {"sweep": list(IN_FLIGHT_SWEEP),
-                    "test_s": TEST_S, "dispatch_s": DISPATCH_S}
-    per_completion = {}
-    for backend in ("polling", "continuation"):
-        rows = {}
-        for n in IN_FLIGHT_SWEEP:
-            _, drain, counters = _progress_setup(backend, n)
-            drain()
-            tests, dispatches = counters()      # deterministic totals
-            ops = tests + dispatches
-            reps = max(1, budget // max(ops, 1))
-            samples = []
-            for _ in range(N_BATCHES):
-                # arm outside the clock: measured_s is the PROGRESS cost
-                # (completion + notification), not machine setup.
-                drains = [_progress_setup(backend, n)[1]
-                          for _ in range(reps)]
-                t0 = time.monotonic()
-                for d in drains:
-                    d()
-                samples.append((time.monotonic() - t0) / reps)
-            # one completion per tick: the mean in-flight count is (n+1)/2
-            predicted = simulate.progress_cost(
-                backend, in_flight=(n + 1) / 2, ticks=n, completions=n,
-                test_s=TEST_S, dispatch_s=DISPATCH_S)
-            rows[f"inflight_{n}"] = {
-                "in_flight": n, "completions": n, "tests": tests,
-                "dispatches": dispatches,
-                "ops_per_completion": ops / n,
-                "measured_s": _median(samples),
-                "predicted_s": predicted,
-                "features": {"rounds": float(ops), "wire_bytes": 0.0,
-                             "combine_bytes": 0.0},
-            }
-        report[backend] = rows
-        per_completion[backend] = {
-            n: rows[f"inflight_{n}"]["ops_per_completion"]
-            for n in IN_FLIGHT_SWEEP}
+    report: dict = {"sweep": list(IN_FLIGHT_SWEEP), "dispatch_s": DISPATCH_S}
+    rows = {}
+    for n in IN_FLIGHT_SWEEP:
+        _, drain, counters = _progress_setup(n)
+        drain()
+        tests, dispatches = counters()          # deterministic totals
+        ops = tests + dispatches
+        reps = max(1, budget // max(ops, 1))
+        samples = []
+        for _ in range(N_BATCHES):
+            # arm outside the clock: measured_s is the PROGRESS cost
+            # (completion + notification), not machine setup.
+            drains = [_progress_setup(n)[1] for _ in range(reps)]
+            t0 = time.monotonic()
+            for d in drains:
+                d()
+            samples.append((time.monotonic() - t0) / reps)
+        rows[f"inflight_{n}"] = {
+            "in_flight": n, "completions": n, "tests": tests,
+            "dispatches": dispatches,
+            "ops_per_completion": ops / n,
+            "measured_s": _median(samples),
+            "predicted_s": DISPATCH_S * n,
+            "features": {"rounds": float(ops), "wire_bytes": 0.0,
+                         "combine_bytes": 0.0},
+        }
+    report["continuation"] = rows
     lo, hi = min(IN_FLIGHT_SWEEP), max(IN_FLIGHT_SWEEP)
-    cont, poll = per_completion["continuation"], per_completion["polling"]
-    if max(cont.values()) > 2.0 or cont[hi] > 1.5 * cont[lo]:
+    per = {n: rows[f"inflight_{n}"]["ops_per_completion"]
+           for n in IN_FLIGHT_SWEEP}
+    if max(per.values()) > 2.0 or per[hi] > 1.5 * per[lo]:
         raise SystemExit(
             f"continuation progress cost is NOT flat in in-flight ops: "
-            f"ops/completion {cont} (expected O(1) dispatches per "
+            f"ops/completion {per} (expected O(1) dispatches per "
             f"completion)")
-    if poll[hi] < 2.0 * poll[lo]:
-        raise SystemExit(
-            f"polling progress cost unexpectedly flat: ops/completion "
-            f"{poll} (the baseline the continuation backend beats)")
     return report
 
 
@@ -662,7 +638,7 @@ def bench_obs(smoke: bool = False) -> dict:
 
     Every instrumentation site in the runtime is guarded by one module
     attribute read (``if trace.TRACING: ...``).  This leg bounds that
-    cost on the hottest instrumented path (the continuation-backend
+    cost on the hottest instrumented path (the continuation-engine
     drain of ``bench_progress``): it counts the guarded emissions one
     drain performs under a real tracer, measures the per-check guard
     cost with tracing disabled, and HARD ASSERTS
@@ -680,7 +656,7 @@ def bench_obs(smoke: bool = False) -> dict:
 
     # (1) guarded emissions per drain, counted under a real tracer.
     with obs.tracing() as tr:
-        _, drain, counters = _progress_setup("continuation", n)
+        _, drain, counters = _progress_setup(n)
         drain()
         n_events = len(tr.events())
         tests, dispatches = counters()
@@ -714,8 +690,7 @@ def bench_obs(smoke: bool = False) -> dict:
             with contextlib.ExitStack() as stack:
                 if active:
                     stack.enter_context(obs.tracing())
-                drains = [_progress_setup("continuation", n)[1]
-                          for _ in range(reps)]
+                drains = [_progress_setup(n)[1] for _ in range(reps)]
                 t0 = time.monotonic()
                 for d in drains:
                     d()
@@ -867,16 +842,15 @@ def bench(print_fn=print, smoke: bool = False,
                          f"payload_bytes={e['payload_bytes']};"
                          f"class={e['overhead_class']}"))
 
-    # polling vs continuation notification: progress cost over an
-    # in-flight sweep (flat vs linear per completion; hard-asserted)
+    # continuation notification: progress cost over an in-flight sweep
+    # (flat per completion; hard-asserted)
     progress = bench_progress(smoke)
     report["progress"] = progress
-    for backend in ("polling", "continuation"):
-        for n in IN_FLIGHT_SWEEP:
-            e = progress[backend][f"inflight_{n}"]
-            rows.append((f"progress_{backend}_{n}", e["measured_s"] * 1e6,
-                         f"tests={e['tests']};dispatches={e['dispatches']};"
-                         f"ops_per_completion={e['ops_per_completion']:.2f}"))
+    for n in IN_FLIGHT_SWEEP:
+        e = progress["continuation"][f"inflight_{n}"]
+        rows.append((f"progress_continuation_{n}", e["measured_s"] * 1e6,
+                     f"tests={e['tests']};dispatches={e['dispatches']};"
+                     f"ops_per_completion={e['ops_per_completion']:.2f}"))
 
     # observability sentinel: NullTracer guard cost bounded (hard assert)
     # + untraced/traced drain rows for the calibrated gate.

@@ -37,7 +37,7 @@ implements that design for the host runtime:
 
   - ``mode="blocking"`` (§6.1): the call returns the rank's result; inside
     a task the rounds are advanced by the progress engine and the task
-    pays a *single* test → register ticket → pause on the completion
+    pays a *single* test → attach → pause on the completion
     handle (one pause per collective, not per round — per-round pausing
     would deadlock help-first nested blocking, whose LIFO stacks cannot
     interleave two in-flight multi-round schedules).  Outside a task (or
@@ -47,10 +47,10 @@ implements that design for the host runtime:
   - ``mode="event"`` (§6.2): the call returns a
     :class:`CollectiveHandle` *immediately* and binds one external event
     to the calling task.  The remaining rounds are advanced by a
-    :class:`ProgressEngine` registered as a polling service — the
+    :class:`ProgressEngine` on the runtime's continuation engine — the
     continuation/progress-engine design of the follow-on papers: no live
     stack, no context switch, sends of later rounds are posted by the
-    polling thread as their inputs arrive.  The task's dependencies are
+    dispatching thread as their inputs arrive.  The task's dependencies are
     released only when the collective completes; successors read
     ``handle.result``.
 
@@ -186,7 +186,7 @@ class CollectiveHandle(tac.EventHandle):
     :class:`~repro.core.tac.RankFailedError`...) completes the handle via
     :meth:`~repro.core.tac.EventHandle.fail`; ``result`` re-raises it on
     whichever thread consumes the collective, so errors surface instead
-    of killing the polling service or hanging ``taskwait``.
+    of killing the dispatching thread or hanging ``taskwait``.
     """
 
 
@@ -199,8 +199,8 @@ class _Machine:
     The generator yields the handle (or list of handles) it waits on; the
     driver sends the received payload(s) back in.  ``advance`` is *not*
     re-entrant: callers must ensure one thread at a time (the progress
-    engine serialises via the polling registry's per-service lock; the
-    group driver is single-threaded).
+    engine arms one continuation per machine at a time; the group driver
+    is single-threaded).
     """
 
     __slots__ = ("gen", "handle", "counter", "comm", "steps", "done",
@@ -246,7 +246,7 @@ class _Machine:
                 decrease_task_event_counter(self.counter, 1)
             return True
         except BaseException as exc:  # noqa: BLE001 - surfaced via handle
-            # A raising schedule must not kill the polling thread or leave
+            # A raising schedule must not kill the dispatching thread or leave
             # the task's event counter bound forever — fail the handle
             # (consumers re-raise) and release the dependency.
             self.done = True
@@ -275,55 +275,35 @@ class ProgressEngine:
     Completion Notification using MPI Continuations*; *MPI Progress For
     All*): completion is detected and *continued* (next rounds posted,
     results combined, dependencies released) by the runtime's progress
-    threads, never by a blocked caller.  Two backends:
+    threads, never by a blocked caller.  Each machine, when it parks on
+    an incomplete wait, **re-arms a continuation on its next awaited
+    handle(s)** via the runtime's
+    :class:`repro.core.continuations.ContinuationEngine`; the machine is
+    advanced exactly when something it waits on completes — O(1)
+    dispatches per completion, zero re-polling, no machine list at all.
+    Event-bound dependency release
+    (:func:`repro.core.events.decrease_task_event_counter`) fires from
+    the continuation callback (inside :meth:`_Machine.advance`).
 
-    * ``notify="polling"`` — a registered polling service re-``advance``s
-      every pending machine each tick: O(in-flight machines) handle
-      tests per poll (``stats["tests"]`` counts them).
-    * ``notify="continuation"`` — each machine, when it parks on an
-      incomplete wait, **re-arms a continuation on its next awaited
-      handle(s)** via the runtime's
-      :class:`repro.core.continuations.ContinuationEngine`; the machine
-      is advanced exactly when something it waits on completes — O(1)
-      dispatches per completion, zero re-polling, no machine list at
-      all.  Event-bound dependency release
-      (:func:`repro.core.events.decrease_task_event_counter`) fires from
-      the continuation callback (inside :meth:`_Machine.advance`).
-
-    ``stats``: ``polls`` (service invocations), ``tests`` (machines
-    re-advanced by polling — the O(in-flight × ticks) term), ``rearms``
-    (continuations armed — O(completions)).
+    ``stats["rearms"]`` counts continuations armed — O(completions).
     """
 
-    def __init__(self, notify: str = "polling",
-                 continuations: Any = None) -> None:
-        if notify not in ("polling", "continuation"):
-            raise ValueError(f"unknown notify backend {notify!r}")
-        if notify == "continuation" and continuations is None:
-            raise ValueError('notify="continuation" needs a '
-                             'ContinuationEngine (continuations=)')
-        self.notify = notify
+    def __init__(self, continuations: Any) -> None:
         self._continuations = continuations
         self._lock = threading.Lock()
-        self._machines: List[_Machine] = []
         self._armed = 0
-        self.stats: Dict[str, int] = {"polls": 0, "tests": 0, "rearms": 0}
+        self.stats: Dict[str, int] = {"rearms": 0}
 
     def submit(self, machine: _Machine) -> None:
         # First advance on the caller's thread (posts the initial sends);
-        # the machine only becomes visible to the poller/continuation if
-        # still pending, so `advance` never runs concurrently.
+        # the machine only becomes visible to the continuation if still
+        # pending, so `advance` never runs concurrently.
         if machine.advance():
             return
-        if self.notify == "continuation":
-            with self._lock:
-                self._armed += 1
-            self._arm(machine)
-            return
         with self._lock:
-            self._machines.append(machine)
+            self._armed += 1
+        self._arm(machine)
 
-    # -- continuation backend ----------------------------------------------
     def _arm(self, machine: _Machine) -> None:
         """Attach a continuation to the machine's next awaited handles."""
         w = machine._waiting
@@ -340,41 +320,21 @@ class ProgressEngine:
         else:
             self._arm(machine)
 
-    # -- polling backend ----------------------------------------------------
-    def poll(self, _data: Any) -> bool:
-        with self._lock:
-            snapshot = list(self._machines)
-            self.stats["polls"] += 1
-            self.stats["tests"] += len(snapshot)
-        finished = [m for m in snapshot if m.advance()]
-        if finished:
-            with self._lock:
-                self._machines = [m for m in self._machines
-                                  if m not in finished]
-        return False  # stay registered
-
     @property
     def pending(self) -> int:
         with self._lock:
-            return len(self._machines) + self._armed
+            return self._armed
 
 
 def _engine(runtime) -> ProgressEngine:
+    # Machines ride the runtime's continuation engine — its single
+    # service; nothing new to register.
     eng = getattr(runtime, "_coll_engine", None)
     if eng is None:
         with runtime._lock:
             eng = getattr(runtime, "_coll_engine", None)
             if eng is None:
-                if getattr(runtime, "notify", "polling") == "continuation":
-                    # Machines ride the runtime's continuation engine —
-                    # its single service; nothing new to register.
-                    eng = ProgressEngine(
-                        notify="continuation",
-                        continuations=runtime.continuations)
-                else:
-                    eng = ProgressEngine()
-                    runtime._register_service(
-                        "collective progress engine", eng.poll)
+                eng = ProgressEngine(runtime.continuations)
                 runtime._coll_engine = eng  # type: ignore[attr-defined]
     return eng
 
@@ -399,8 +359,8 @@ def _execute_schedule(gen, mode: str, comm=None):
     Shared by every collective family (world-wide, neighbourhood,
     hierarchical, persistent).  Outside a task (or without TASK_MULTIPLE)
     the schedule is driven inline with OS-level waits — the PMPI path.
-    Inside a task the progress engine advances the rounds from the polling
-    service: ``blocking`` pays one pause on the completion handle,
+    Inside a task the progress engine advances the rounds from completion
+    dispatch: ``blocking`` pays one pause on the completion handle,
     ``event`` binds the handle to the task's event counter and returns it
     immediately.  ``comm`` is the communicator to revoke if a peer dies
     mid-schedule (see :meth:`_Machine.advance`).

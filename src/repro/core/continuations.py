@@ -45,10 +45,11 @@ This module is that notification engine for the host runtime:
   operation handle.
 
 The engine keeps honest counters (``stats``): with N in-flight
-event-bound operations the continuation path performs **O(completions)**
-callback dispatches, where the polling path performs **O(in-flight ×
-ticks)** tests — the scaling claim `benchmarks/overlap_bench.py`
-measures and `tests/test_continuations.py` asserts.
+push-capable operations it performs **O(completions)** callback
+dispatches and zero tests, where re-testing every operation per tick
+would cost O(in-flight × ticks) — the flat per-completion cost
+`benchmarks/overlap_bench.py` measures and `tests/test_continuations.py`
+asserts.
 """
 
 from __future__ import annotations
@@ -211,24 +212,13 @@ class ContinuationEngine:
     truth after multi-threaded runs); only inter-counter ordering is
     relaxed, so a mid-flight snapshot may transiently show
     ``dispatches < completions``.
-
-    ``push=False`` is the **legacy polling compatibility mode**: every
-    attached handle — push-capable or not — rides the fallback poll list
-    and is re-``test``-ed per service tick, reproducing the retired TAC
-    ticket pool's O(in-flight × ticks) behaviour on the engine's own
-    queue/dispatch path.  ``TaskRuntime(notify="polling")`` builds its
-    engine this way, so the continuation engine is the ONE completion
-    dispatcher under either backend and only the notification *discipline*
-    (push at match time vs re-test per tick) differs.
     """
 
-    def __init__(self, *, queue_capacity: int = 1024,
-                 push: bool = True) -> None:
+    def __init__(self, *, queue_capacity: int = 1024) -> None:
         if queue_capacity < 1:
             raise ValueError(f"queue_capacity must be >= 1, got "
                              f"{queue_capacity}")
         self.queue_capacity = queue_capacity
-        self.push = push
         self._lock = threading.Lock()
         self._queue: collections.deque = collections.deque()
         self._polled: List[tuple] = []      # (handle, _Pending) fallbacks
@@ -273,7 +263,7 @@ class ContinuationEngine:
         if _tr.TRACING:
             _tr.TRACER.instant("continuation", "attach", n_handles=len(hs))
         for h in hs:
-            push = getattr(h, "on_complete", None) if self.push else None
+            push = getattr(h, "on_complete", None)
             if callable(push):
                 # Push path: the handle calls back at match time — this
                 # operation is never tested again.
@@ -306,7 +296,7 @@ class ContinuationEngine:
             _tr.TRACER.instant("continuation", "dispatch")
             if rec._ready_at is not None:
                 # Queue->callback latency: the per-completion dispatch
-                # term of simulate.progress_cost, measured.
+                # term that Simulator(dispatch_overhead=) models.
                 _REGISTRY.histogram(
                     "continuation.dispatch_latency_s").observe(
                         time.monotonic() - rec._ready_at)

@@ -43,7 +43,6 @@ Additional production features beyond the paper:
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -55,8 +54,6 @@ from .events import BlockingContext, set_current_task, current_task
 from .polling import PollingRegistry
 from .taskgraph import (Task, TaskGraph, CREATED, READY, RUNNING, BLOCKED,
                         FINISHED, RELEASED)
-
-NOTIFY_BACKENDS = ("polling", "continuation")
 
 
 class TaskError(RuntimeError):
@@ -75,23 +72,11 @@ class TaskRuntime:
                  poll_interval: float = 0.001,
                  block_mode: str = "spare-thread",
                  max_threads: Optional[int] = None,
-                 speculative_timeout: Optional[float] = None,
-                 notify: Optional[str] = None) -> None:
+                 speculative_timeout: Optional[float] = None) -> None:
         if block_mode not in ("spare-thread", "nested"):
             raise ValueError(f"unknown block_mode {block_mode!r}")
-        if notify is None:
-            # Continuation notification is the default (O(completions)
-            # dispatches; ROADMAP carry-over after the CI soak); the env
-            # override lets the whole tier-1 suite run under either
-            # backend unchanged (CI exercises REPRO_NOTIFY=polling to
-            # keep the legacy backend covered).
-            notify = os.environ.get("REPRO_NOTIFY") or "continuation"
-        if notify not in NOTIFY_BACKENDS:
-            raise ValueError(f"unknown notify backend {notify!r}; "
-                             f"one of {NOTIFY_BACKENDS}")
         self.num_workers = num_workers
         self.block_mode = block_mode
-        self.notify = notify
         self.poll_interval = poll_interval
         self.max_threads = max_threads or num_workers + 512
         self.speculative_timeout = speculative_timeout
@@ -131,24 +116,20 @@ class TaskRuntime:
         One engine — and ONE registered polling service — per runtime:
         the ONLY completion dispatcher behind
         :func:`repro.core.tac.wait`/``iwait``/``iwaitall`` and the
-        collectives :class:`~repro.core.collectives.ProgressEngine`
-        (the legacy TAC ticket pool was folded into it).  Under
-        ``notify="continuation"`` push-capable handles notify at match
-        time; under ``notify="polling"`` the SAME engine runs in its
-        compatibility mode (``push=False``): every handle rides the
-        fallback poll list and is re-tested per tick, preserving the
-        paper's §4.2 polling discipline.  Ready callbacks are dispatched
-        by the dedicated poller, by idle workers (§4.5), and at the
-        scheduling points (``submit``/``taskwait``) which drain the
-        bounded completion queue.
+        collectives :class:`~repro.core.collectives.ProgressEngine`.
+        Push-capable handles notify it at match time; push-less ones
+        (JAX arrays) ride its fallback poll list, re-tested by that one
+        service.  Ready callbacks are dispatched by the dedicated poller,
+        by idle workers (§4.5), and at the scheduling points
+        (``submit``/``taskwait``) which drain the bounded completion
+        queue.
         """
         eng = self._continuations
         if eng is None:
             with self._lock:
                 eng = self._continuations
                 if eng is None:
-                    eng = ContinuationEngine(
-                        push=(self.notify == "continuation"))
+                    eng = ContinuationEngine()
                     self._register_service("continuation engine",
                                            eng.service)
                     self._continuations = eng

@@ -13,9 +13,9 @@ runtime, built on the failure model in :mod:`repro.core.tac`:
   rank: every pending handle naming it completes erroneously with
   :class:`~repro.core.tac.RankFailedError`, *pushed* through the
   handles' completion callbacks — the continuation engine dispatches the
-  failure exactly like a success, so neither notification backend gains
-  a single new poll.  Failures are observable at task granularity: a
-  dead peer is a raising ``handle.result`` / ``taskwait``, never a hang.
+  failure exactly like a success, without a single new poll.  Failures
+  are observable at task granularity: a dead peer is a raising
+  ``handle.result`` / ``taskwait``, never a hang.
 
 * **Propagation** — a collective machine that observes a
   ``RankFailedError`` revokes its communicator

@@ -103,13 +103,11 @@ class Simulator:
     """List-scheduling discrete-event simulator over per-rank worker pools.
 
     ``dispatch_overhead`` models the completion-notification cost of the
-    continuation backend (:mod:`repro.core.continuations`): when the
+    continuation engine (:mod:`repro.core.continuations`): when the
     *last* pending event of a task arrives, its completion callback is
     dispatched from the engine's queue ``dispatch_overhead`` seconds
-    later — the per-completion term of :func:`progress_cost`.  The
-    polling backend's per-tick re-test cost is not an event in this DAG
-    model (it scales with wall time, not with the graph); use
-    :func:`progress_cost` to account for it analytically.
+    later — the per-completion dispatch term, measured on the runtime by
+    the ``continuation.dispatch_latency_s`` histogram.
     """
 
     def __init__(self, n_ranks: int, workers_per_rank: int, *,
@@ -317,7 +315,7 @@ class Simulator:
                 task._pending_events -= 1
                 if task._pending_events == 0 and task._body_done_at is not None:
                     if self.dispatch_overhead > 0.0:
-                        # Continuation backend: the completion callback is
+                        # Continuation engine: the completion callback is
                         # dispatched from the queue, one overhead later.
                         push(now + self.dispatch_overhead, "event-fire",
                              task.id)
@@ -400,40 +398,6 @@ def trace_events(tasks: List[SimTask]) -> List[dict]:
                     task=t.name or str(t.id), mode="sim", source="sim"))
     events.sort(key=lambda e: e["ts"])
     return events
-
-
-# ---------------------------------------------------------------------------
-# Progress-path cost: the α-β term of the two notification backends
-# ---------------------------------------------------------------------------
-def progress_cost(backend: str, *, in_flight: float, ticks: float,
-                  completions: float, test_s: float,
-                  dispatch_s: float) -> float:
-    """Analytic progress-engine cost of one notification backend.
-
-    The α-β model's missing term: moving bytes is only part of a
-    communication task's cost — somebody must also *notice* completions.
-
-    * ``"polling"`` — the registry re-tests every in-flight operation
-      each tick and pays a dispatch per completion:
-      ``test_s·in_flight·ticks + dispatch_s·completions``.  Per tick the
-      cost is **linear in the number of in-flight operations**, even
-      when nothing completed.
-    * ``"continuation"`` — completions are pushed at match time and only
-      ready callbacks are dispatched: ``dispatch_s·completions``.  Per
-      tick the cost is **flat** (zero when nothing completed), total
-      work O(completions) regardless of how many operations are parked.
-
-    The discrete-event counterpart of the dispatch term is
-    ``Simulator(dispatch_overhead=dispatch_s)``;
-    ``benchmarks/overlap_bench.py`` measures both backends against this
-    model over an in-flight sweep.
-    """
-    if backend == "continuation":
-        return dispatch_s * completions
-    if backend == "polling":
-        return test_s * in_flight * ticks + dispatch_s * completions
-    raise ValueError(f"unknown backend {backend!r}; "
-                     f"one of ('polling', 'continuation')")
 
 
 # ---------------------------------------------------------------------------

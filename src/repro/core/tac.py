@@ -9,8 +9,8 @@ point-to-point semantics between logical ranks.  TAC wraps those operations
 in the two modes the paper defines:
 
 * **Blocking mode** (§6.1, Fig. 3): ``tac.wait(handle)`` from inside a task
-  converts a blocking wait into *test → register ticket → pause task*; a
-  polling service tests the pending tickets and unblocks tasks on
+  converts a blocking wait into *test → attach a resume callback → pause
+  task*; the runtime's continuation engine unblocks the task on
   completion.  The hardware thread never blocks inside the "MPI library".
 
 * **Non-blocking mode** (§6.2, Fig. 4): ``tac.iwait(handle)`` /
@@ -32,7 +32,6 @@ import itertools
 import math
 import threading
 import time
-import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -43,8 +42,7 @@ from .events import (current_task, get_current_blocking_context,
                      get_current_event_counter,
                      increase_current_task_event_counter,
                      decrease_task_event_counter, block_current_task,
-                     unblock_task, BlockingContext, EventCounter)
-from .executor import TaskRuntime
+                     unblock_task)
 
 # -- threading levels (§6.3) -------------------------------------------------
 THREAD_SINGLE = 0
@@ -592,8 +590,8 @@ class CommWorld:
 
         Every *pending* send/recv naming the dead rank completes
         erroneously with :class:`RankFailedError` — pushed through the
-        handles' completion callbacks, so both notification backends
-        observe the failure with zero new polling.  Every *future* op
+        handles' completion callbacks, so the continuation engine
+        observes the failure with zero new polling.  Every *future* op
         naming it fails at post time.  Pending ``split`` generations can
         never complete (they need all ranks) and are failed too.  The
         communicator epoch is bumped, invalidating epoch-keyed compiled
@@ -1119,78 +1117,6 @@ def as_handle(obj: Any) -> AsyncHandle:
 
 
 # ---------------------------------------------------------------------------
-# Deprecated ticket-pool shims (pre-fold entry points)
-# ---------------------------------------------------------------------------
-def _ticket_pool_deprecated(name: str) -> None:
-    warnings.warn(
-        f"tac.{name} is deprecated: the TAC ticket pool was folded into "
-        f"the runtime's ContinuationEngine (runtime.continuations), the "
-        f"single completion dispatcher for both notify backends; attach "
-        f"callbacks there instead",
-        DeprecationWarning, stacklevel=3)
-
-
-class _Ticket:
-    """Deprecated record of the retired ticket pool (shim)."""
-
-    __slots__ = ("handle", "waiter", "counter", "n_events")
-
-    def __init__(self, handle: AsyncHandle,
-                 waiter: Optional[BlockingContext] = None,
-                 counter: Optional[EventCounter] = None,
-                 n_events: int = 1) -> None:
-        _ticket_pool_deprecated("_Ticket")
-        self.handle = handle
-        self.waiter = waiter      # blocking mode: context to unblock
-        self.counter = counter    # non-blocking mode: counter to decrease
-        self.n_events = n_events
-
-
-class _TicketPool:
-    """Deprecated facade over the runtime's :class:`ContinuationEngine`.
-
-    The ticket pool is no longer an independent completion path: ``add``
-    attaches the ticket's unblock/decrease action to the continuation
-    engine (which re-tests push-less handles from its fallback poll list
-    — the old pool's discipline), and ``pending`` reads the engine's
-    fallback-list length.  No polling service of its own is registered.
-    """
-
-    def __init__(self, runtime: TaskRuntime) -> None:
-        _ticket_pool_deprecated("_TicketPool")
-        self._runtime = runtime
-
-    def add(self, ticket: _Ticket) -> None:
-        eng = self._runtime.continuations
-        if ticket.waiter is not None:
-            waiter = ticket.waiter
-            eng.attach(ticket.handle, lambda: unblock_task(waiter))
-        if ticket.counter is not None:
-            counter, n = ticket.counter, ticket.n_events
-            eng.attach(ticket.handle,
-                       lambda: decrease_task_event_counter(counter, n))
-
-    @property
-    def pending(self) -> int:
-        return self._runtime.continuations.polled
-
-
-def _pool(runtime: TaskRuntime) -> _TicketPool:
-    _ticket_pool_deprecated("_pool")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        return _TicketPool(runtime)
-
-
-def _use_continuations(runtime: TaskRuntime) -> bool:
-    """Deprecated: the continuation engine is the only completion
-    dispatcher now; ``notify="polling"`` is a compatibility mode of the
-    SAME engine (``push=False``), so there is no branch left to take."""
-    _ticket_pool_deprecated("_use_continuations")
-    return True
-
-
-# ---------------------------------------------------------------------------
 # The two interoperability modes
 # ---------------------------------------------------------------------------
 def wait(handle: Any) -> Any:
@@ -1200,9 +1126,7 @@ def wait(handle: Any) -> Any:
     TASK_MULTIPLE enabled: test; if incomplete, attach a resume callback
     to the runtime's continuation engine and *pause the task* — the
     worker runs other ready tasks until the completion dispatch unblocks
-    us (pushed at match time under ``notify="continuation"``; re-tested
-    from the engine's poll list under the ``notify="polling"``
-    compatibility mode).  Otherwise: plain blocking wait (the PMPI path).
+    us.  Otherwise: plain blocking wait (the PMPI path).
     """
     handle = as_handle(handle)
     task = current_task()

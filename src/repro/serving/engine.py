@@ -97,7 +97,6 @@ class ServingEngine:
                  completion: str = "event",
                  runtime: Optional[TaskRuntime] = None,
                  num_workers: Optional[int] = None,
-                 notify: Optional[str] = None,
                  sync_every: int = 0,
                  world: Any = None, tp_elems: int = 0,
                  on_round: Optional[Callable[["ServingEngine", int],
@@ -125,7 +124,6 @@ class ServingEngine:
         self.on_round = on_round
         self._ext_runtime = runtime
         self._num_workers = num_workers or max(4, slots + 2)
-        self._notify = notify
 
         self._world = world
         self._tp_elems = tp_elems
@@ -381,8 +379,7 @@ class ServingEngine:
     def run(self, requests: Sequence[Request]) -> ServeReport:
         """Serve ``requests`` (arrival times honoured) to completion."""
         pending = sorted(requests, key=lambda r: (r.arrival_s, r.rid))
-        rt = self._ext_runtime or TaskRuntime(
-            num_workers=self._num_workers, notify=self._notify)
+        rt = self._ext_runtime or TaskRuntime(num_workers=self._num_workers)
         rt.start()
         self._t0 = time.monotonic()
         rounds = 0

@@ -9,8 +9,11 @@ failure surfaced on every rank, then runs the full ULFM recovery
 
 The harness asserts the protocol's *shape* (no hangs, leak-free
 teardown, every rank either a result or a failure error); the caller
-asserts the *semantics* (which ranks failed, survivor numerics).  Used
-by tests/test_resilience.py both directly and under hypothesis.
+asserts the *semantics* (which ranks failed, survivor numerics).  A
+death point past the victim's last posted operation never fires: the
+outcome says so (``fired``) and the harness then asserts the clean
+result instead.  Used by tests/test_resilience.py both directly and
+under hypothesis.
 """
 
 from __future__ import annotations
@@ -32,6 +35,7 @@ class FaultOutcome:
     """What one injected failure did to an n-rank collective."""
     world: tac.CommWorld
     values: List[np.ndarray]
+    fired: bool = False            # the armed trap killed the victim
     results: Dict[int, Any] = field(default_factory=dict)   # rank -> value
     errors: Dict[int, BaseException] = field(default_factory=dict)
     survivors: Any = None          # CommGroup after recovery, or None
@@ -58,7 +62,7 @@ def _resolve(store: Dict[int, Any], outcome: FaultOutcome) -> None:
 
 def run_with_failure(*, n_ranks: int, victim: int, after_ops: int = 1,
                      algorithm: str = "ring", mode: str = "event",
-                     notify: Optional[str] = None, op: str = "allreduce",
+                     op: str = "allreduce",
                      hierarchical: Optional[int] = None, workers: int = 2,
                      recover_after: bool = True,
                      seed: int = 0) -> FaultOutcome:
@@ -72,9 +76,13 @@ def run_with_failure(*, n_ranks: int, victim: int, after_ops: int = 1,
     * every rank lands in exactly one of ``results`` / ``errors``;
     * the runtime closes leak-free (no registered polling services).
 
-    With ``recover_after`` the survivors then revoke + shrink and re-run
-    the collective on the shrunken group (sequential driver), filling
-    ``outcome.survivors`` / ``outcome.recovered``.
+    If the victim posts fewer than ``after_ops`` operations the trap
+    never fires (``outcome.fired`` is False): then no rank may fail and
+    every rank's result must equal the numpy reference, and there is
+    nothing to recover from.  Otherwise, with ``recover_after``, the
+    survivors revoke + shrink and re-run the collective on the shrunken
+    group (sequential driver), filling ``outcome.survivors`` /
+    ``outcome.recovered``.
     """
     tac.init(tac.TASK_MULTIPLE)
     world = tac.CommWorld(n_ranks)
@@ -93,7 +101,7 @@ def run_with_failure(*, n_ranks: int, victim: int, after_ops: int = 1,
                                       key="fh", **kw)
         return run
 
-    rt = TaskRuntime(num_workers=workers, notify=notify)
+    rt = TaskRuntime(num_workers=workers)
     rt.start()
     try:
         injector.arm(victim, after_ops=after_ops)
@@ -113,6 +121,7 @@ def run_with_failure(*, n_ranks: int, victim: int, after_ops: int = 1,
             except TaskError:
                 continue
     finally:
+        outcome.fired = not injector.armed
         injector.disarm()
         rt.close()
     assert rt.polling.num_services == 0, "leaked polling services"
@@ -122,6 +131,13 @@ def run_with_failure(*, n_ranks: int, victim: int, after_ops: int = 1,
     # from both maps IS the error record
     if mode == "event":
         assert claimed == set(range(n_ranks)), claimed
+    if not outcome.fired:
+        assert not outcome.errors, outcome.errors
+        ref = np.sum(values, axis=0)
+        assert outcome.ok_ranks == list(range(n_ranks)), outcome.ok_ranks
+        for v in outcome.results.values():
+            np.testing.assert_allclose(v, ref, rtol=1e-10, atol=1e-12)
+        return outcome
     assert outcome.errors or len(outcome.results) < n_ranks, \
         "injected failure was not observed anywhere"
 

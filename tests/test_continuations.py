@@ -1,13 +1,13 @@
 """Continuation-based completion notification (core/continuations.py).
 
 Covers the engine itself (attach, all-of sets, chaining, bounded queues,
-poll fallback, error capture), the ProgressEngine ``notify="continuation"``
-backend (O(completions) dispatches vs the polling backend's
-O(in-flight × ticks) tests — the acceptance-criterion counters), the
-runtime wiring (TaskRuntime.continuations, wait/iwait routing,
-scheduling-point drains, deterministic service teardown), the §5
-single-worker deadlock regression under nested blocking + continuation
-notification, and the simulator's callback-dispatch cost model.
+poll fallback, error capture), the collectives ProgressEngine riding it
+(O(completions) dispatches and zero tests — the acceptance-criterion
+counters), the runtime wiring (TaskRuntime.continuations, wait/iwait
+routing for push and push-less handles, scheduling-point drains,
+deterministic service teardown), the §5 single-worker deadlock
+regression under nested blocking, and the simulator's callback-dispatch
+cost model.
 """
 
 import threading
@@ -19,8 +19,7 @@ from repro.core import Collectives, TaskRuntime, tac
 from repro.core.collectives import (CollectiveHandle, HaloExchange,
                                     ProgressEngine, _Machine)
 from repro.core.continuations import Continuation, ContinuationEngine
-from repro.core.simulate import (COMM_EVENTS, COMPUTE, SimTask, Simulator,
-                                 progress_cost)
+from repro.core.simulate import COMM_EVENTS, COMPUTE, SimTask, Simulator
 
 
 @pytest.fixture(autouse=True)
@@ -188,7 +187,7 @@ def test_continuation_is_a_waitable_handle():
 
 
 # ---------------------------------------------------------------------------
-# the acceptance-criterion counters: O(completions) vs O(in-flight × ticks)
+# the acceptance-criterion counters: O(completions), zero tests
 # ---------------------------------------------------------------------------
 def _event_machines(engine, n):
     """n in-flight machines, each waiting on one EventHandle."""
@@ -205,36 +204,29 @@ def _event_machines(engine, n):
 
 def test_progress_counters_flat_vs_linear():
     """64 in-flight event-bound operations, one completion per tick: the
-    polling backend performs O(in-flight × ticks) tests, the continuation
-    backend O(completions) dispatches and ZERO tests."""
+    progress engine performs O(completions) dispatches and ZERO tests —
+    flat per completion, where re-testing every parked machine per tick
+    would grow linearly with the in-flight count."""
     n = 64
-
-    poll_eng = ProgressEngine()
-    handles = _event_machines(poll_eng, n)
-    for i, h in enumerate(handles):
-        h.complete(i)
-        poll_eng.poll(None)
-    assert poll_eng.pending == 0
-    assert poll_eng.stats["tests"] == n * (n + 1) // 2   # 2080: Σ in-flight
-
     engine = ContinuationEngine()
-    cont_eng = ProgressEngine(notify="continuation", continuations=engine)
-    handles = _event_machines(cont_eng, n)
+    eng = ProgressEngine(engine)
+    handles = _event_machines(eng, n)
+    assert eng.pending == n
     for i, h in enumerate(handles):
         h.complete(i)
         engine.service(None)
-    assert cont_eng.pending == 0
+    assert eng.pending == 0
+    assert engine.stats["completions"] == n
     assert engine.stats["dispatches"] == n               # one per completion
     assert engine.stats["tests"] == 0                    # pure push
-    assert cont_eng.stats["rearms"] == n
-    assert cont_eng.stats["tests"] == 0                  # never re-polled
+    assert eng.stats["rearms"] == n
 
 
 def test_continuation_machine_rearms_across_rounds():
     """A multi-wait machine re-arms a continuation per awaited handle —
     dispatches stay O(completions), not O(machines × ticks)."""
     engine = ContinuationEngine()
-    eng = ProgressEngine(notify="continuation", continuations=engine)
+    eng = ProgressEngine(engine)
     h1, h2, h3 = (tac.EventHandle() for _ in range(3))
 
     def gen():
@@ -256,23 +248,14 @@ def test_continuation_machine_rearms_across_rounds():
     assert eng.stats["rearms"] == 2           # one per parked wait
 
 
-def test_progress_engine_validates_backend():
-    with pytest.raises(ValueError):
-        ProgressEngine(notify="wat")
-    with pytest.raises(ValueError):
-        ProgressEngine(notify="continuation")   # engine required
-
-
 # ---------------------------------------------------------------------------
-# runtime wiring: both backends drive the full collective stack
+# runtime wiring: the continuation engine drives the full collective stack
 # ---------------------------------------------------------------------------
-BACKENDS = ("polling", "continuation")
-
-
-@pytest.mark.parametrize("notify", BACKENDS)
-def test_many_in_flight_event_collectives_stress(notify):
+@pytest.mark.parametrize("algorithm", ["ring", "doubling"])
+def test_many_in_flight_event_collectives_stress(algorithm):
     """≥64 concurrent event-bound collectives (8 ranks × 8 keyed
-    allreduces) under each notification backend."""
+    allreduces) in each schedule family, all riding the continuation
+    engine."""
     n, per_rank = 8, 8
     _, coll = _world(n)
     vals = {(r, k): np.full(4, float(r + 1) * (k + 1))
@@ -285,11 +268,11 @@ def test_many_in_flight_event_collectives_stress(notify):
         def body():
             for k in range(per_rank):
                 handles[(r, k)] = coll.allreduce(
-                    vals[(r, k)], rank=r, op="sum", algorithm="ring",
+                    vals[(r, k)], rank=r, op="sum", algorithm=algorithm,
                     mode="event", key=("stress", k))
         return body
 
-    with TaskRuntime(num_workers=4, notify=notify) as rt:
+    with TaskRuntime(num_workers=4) as rt:
         for r in range(n):
             rt.submit(comm(r))
         rt.taskwait()
@@ -297,16 +280,17 @@ def test_many_in_flight_event_collectives_stress(notify):
     for (r, k), h in handles.items():
         np.testing.assert_allclose(h.result, refs[k])
     assert rt.stats.get("task_blocks", 0) == 0
-    if notify == "continuation":
-        # machines rode the continuation engine, not a polled list
-        assert rt._coll_engine.notify == "continuation"
-        assert rt.continuations.stats["dispatches"] > 0
+    # machines rode the runtime's continuation engine, not a polled list
+    assert rt._coll_engine._continuations is rt.continuations
+    assert rt._coll_engine.stats["rearms"] > 0
+    assert rt._coll_engine.pending == 0
+    assert rt.continuations.stats["dispatches"] > 0
     rt.close()
     assert rt.polling.num_services == 0      # deterministic teardown
 
 
-@pytest.mark.parametrize("notify", BACKENDS)
-def test_blocking_collectives_both_backends(notify):
+@pytest.mark.parametrize("block_mode", ["spare-thread", "nested"])
+def test_blocking_collectives(block_mode):
     n = 5
     _, coll = _world(n)
     vals = [np.arange(6.0) * (r + 1) for r in range(n)]
@@ -319,7 +303,7 @@ def test_blocking_collectives_both_backends(notify):
                                         mode="blocking", key="b")
         return body
 
-    with TaskRuntime(num_workers=2, notify=notify) as rt:
+    with TaskRuntime(num_workers=2, block_mode=block_mode) as rt:
         for r in range(n):
             rt.submit(make(r))
         rt.taskwait()
@@ -329,10 +313,10 @@ def test_blocking_collectives_both_backends(notify):
 
 
 def test_nested_single_worker_deadlock_regression_continuation():
-    """§5 with block_mode="nested", ONE worker and continuation
-    notification: the blocked task's stack serves the engine's service
-    (dispatching ready callbacks) while it helps, so the multi-round
-    blocking collective completes without spare threads."""
+    """§5 with block_mode="nested" and ONE worker: the blocked task's
+    stack serves the engine's service (dispatching ready callbacks)
+    while it helps, so the multi-round blocking collective completes
+    without spare threads."""
     n = 3
     _, coll = _world(n)
     results = {}
@@ -344,8 +328,7 @@ def test_nested_single_worker_deadlock_regression_continuation():
                                         key="nc")
         return body
 
-    with TaskRuntime(num_workers=1, block_mode="nested",
-                     notify="continuation") as rt:
+    with TaskRuntime(num_workers=1, block_mode="nested") as rt:
         for r in range(n):
             rt.submit(make(r))
         rt.taskwait()
@@ -353,11 +336,32 @@ def test_nested_single_worker_deadlock_regression_continuation():
     assert rt.stats["threads_spawned"] == 1   # no spares in nested mode
 
 
-@pytest.mark.parametrize("notify", BACKENDS)
-def test_wait_iwait_routing(notify):
+class _PushLess:
+    """A view of a handle with only the base protocol — no
+    ``on_complete`` — so the engine re-tests it from its fallback poll
+    list, as it does the JAX arrays a device step returns."""
+
+    def __init__(self, handle):
+        self._h = handle
+
+    def test(self):
+        return self._h.test()
+
+    def wait(self):
+        return self._h.wait()
+
+    @property
+    def result(self):
+        return self._h.result
+
+
+@pytest.mark.parametrize("intake", ["push", "fallback"])
+def test_wait_iwait_routing(intake):
     """tac.wait pauses/resumes and tac.iwait(all) defers release through
-    whichever backend the runtime selected."""
+    the continuation engine, whether the handles push their completion
+    or ride the fallback poll list."""
     done = {}
+    view = (lambda h: h) if intake == "push" else _PushLess
 
     def producer(handles):
         def body():
@@ -369,16 +373,16 @@ def test_wait_iwait_routing(notify):
     h_i1, h_i2, h_i3 = (tac.EventHandle() for _ in range(3))
 
     def waiter():
-        done["wait"] = tac.wait(h_wait)
+        done["wait"] = tac.wait(view(h_wait))
 
     def binder():
-        tac.iwait(h_i1)
-        tac.iwaitall([h_i2, h_i3])
+        tac.iwait(view(h_i1))
+        tac.iwaitall([view(h_i2), view(h_i3)])
 
     def consumer():
         done["iwait"] = (h_i1.result, h_i2.result, h_i3.result)
 
-    with TaskRuntime(num_workers=2, notify=notify) as rt:
+    with TaskRuntime(num_workers=2) as rt:
         rt.submit(binder, out=["b"])
         rt.submit(waiter, out=["w"])
         rt.submit(producer([h_wait, h_i1, h_i2, h_i3]))
@@ -386,6 +390,10 @@ def test_wait_iwait_routing(notify):
         rt.taskwait()
     assert done["wait"] == 0
     assert done["iwait"] == (1, 2, 3)
+    if intake == "push":
+        assert rt.continuations.stats["tests"] == 0
+    else:
+        assert rt.continuations.stats["tests"] > 0
 
 
 def test_failing_collective_releases_and_teardown_clean():
@@ -405,19 +413,16 @@ def test_failing_collective_releases_and_teardown_clean():
                 algorithm="doubling", mode="event", key="bad")
         return body
 
-    for notify in BACKENDS:
-        handles.clear()
-        rt = TaskRuntime(num_workers=2, notify=notify)
-        with rt:
-            for r in range(n):
-                rt.submit(capture(r))
-            rt.taskwait()                     # must not hang
-        failed = [r for r in range(n) if handles[r].error is not None]
-        assert failed
-        with pytest.raises(ValueError):
-            _ = handles[failed[0]].result
-        assert rt.polling.num_services == 0, \
-            f"{notify}: services leaked past close()"
+    rt = TaskRuntime(num_workers=2)
+    with rt:
+        for r in range(n):
+            rt.submit(capture(r))
+        rt.taskwait()                         # must not hang
+    failed = [r for r in range(n) if handles[r].error is not None]
+    assert failed
+    with pytest.raises(ValueError):
+        _ = handles[failed[0]].result
+    assert rt.polling.num_services == 0, "services leaked past close()"
 
 
 def test_close_unregisters_every_runtime_service():
@@ -432,7 +437,7 @@ def test_close_unregisters_every_runtime_service():
     rt.submit(body)
     h.complete(None)
     rt.taskwait()
-    # ticket pool / continuation engine / straggler watch all registered
+    # continuation engine / straggler watch both registered
     assert rt.polling.num_services >= 2
     rt.close()
     assert rt.polling.num_services == 0
@@ -440,7 +445,7 @@ def test_close_unregisters_every_runtime_service():
 
 def test_one_service_total_not_one_per_operation():
     """100 attached operations: still exactly ONE registered service."""
-    with TaskRuntime(num_workers=2, notify="continuation") as rt:
+    with TaskRuntime(num_workers=2) as rt:
         before = rt.polling.num_services
         hs = [tac.EventHandle() for _ in range(100)]
 
@@ -456,7 +461,7 @@ def test_one_service_total_not_one_per_operation():
 
 
 # ---------------------------------------------------------------------------
-# neighbourhood + chained waits end-to-end under continuation notify
+# neighbourhood + chained waits end-to-end
 # ---------------------------------------------------------------------------
 def test_halo_exchange_event_mode_continuation_backend():
     w = tac.CommWorld(4)
@@ -471,7 +476,7 @@ def test_halo_exchange_event_mode_continuation_backend():
             got[r] = hx.start(sends, rank=r, mode="event", key="h")
         return body
 
-    with TaskRuntime(num_workers=2, notify="continuation") as rt:
+    with TaskRuntime(num_workers=2) as rt:
         for r in range(4):
             rt.submit(comm(r))
         rt.taskwait()
@@ -494,14 +499,14 @@ def test_task_waits_on_chained_continuation():
         tac.wait(c2)
         out["result"] = c1.result
 
-    with TaskRuntime(num_workers=1, notify="continuation") as rt:
+    with TaskRuntime(num_workers=1) as rt:
         rt.submit(body)
         rt.taskwait()
     assert out == {"first": 1, "second": 2, "result": "done"}
 
 
 # ---------------------------------------------------------------------------
-# simulator: callback-dispatch cost + the analytic progress model
+# simulator: callback-dispatch cost
 # ---------------------------------------------------------------------------
 def _two_task_event_graph():
     return [
@@ -527,30 +532,6 @@ def test_simulator_dispatch_overhead_zero_is_identity():
                              for t in tasks]).makespan
     b = Simulator(1, 2, dispatch_overhead=0.0).run(tasks).makespan
     assert a == b
-
-
-def test_progress_cost_model():
-    # polling: linear in in-flight × ticks; continuation: completions only
-    p = progress_cost("polling", in_flight=64, ticks=100, completions=10,
-                      test_s=1e-6, dispatch_s=2e-6)
-    c = progress_cost("continuation", in_flight=64, ticks=100,
-                      completions=10, test_s=1e-6, dispatch_s=2e-6)
-    assert p == pytest.approx(64 * 100 * 1e-6 + 10 * 2e-6)
-    assert c == pytest.approx(10 * 2e-6)
-    # doubling the in-flight count doubles polling, leaves continuation flat
-    p2 = progress_cost("polling", in_flight=128, ticks=100, completions=10,
-                       test_s=1e-6, dispatch_s=2e-6)
-    c2 = progress_cost("continuation", in_flight=128, ticks=100,
-                       completions=10, test_s=1e-6, dispatch_s=2e-6)
-    assert p2 > 1.9 * p and c2 == c
-    with pytest.raises(ValueError):
-        progress_cost("wat", in_flight=1, ticks=1, completions=1,
-                      test_s=1, dispatch_s=1)
-
-
-def test_runtime_rejects_unknown_notify():
-    with pytest.raises(ValueError):
-        TaskRuntime(notify="wat")
 
 
 # ---------------------------------------------------------------------------

@@ -146,8 +146,7 @@ def test_parity_halo_and_persistent():
 
 
 # ---------------------------------------------------------------------------
-# interoperability modes inside a runtime (CI runs this file under both
-# REPRO_NOTIFY backends)
+# interoperability modes inside a runtime
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("mode", ["blocking", "event"])
 def test_mode_parity_inside_tasks(mode):

@@ -14,15 +14,16 @@ Layers under test (PR tentpole):
    bumps (``StaleProgramError``); persistent collectives and halo
    exchanges recompile themselves on first post after recovery.
 4. **FaultInjector + harness** — deterministic mid-operation death; the
-   hypothesis sweep drives failure point × algorithm × mode × notify
-   backend through tests/fault_harness.py and asserts hang-free
-   surfacing, leak-free teardown, and survivor convergence.
+   hypothesis sweep drives failure point × algorithm × mode through
+   tests/fault_harness.py and asserts hang-free surfacing, leak-free
+   teardown, and survivor convergence (or, for a death point the
+   victim never reaches, the clean result).
 5. **Simulator rank death** — ``Simulator.run(fail=...)`` reports the
    failure cone instead of deadlocking.
 
 The whole module carries the ``faults`` marker: the CI soak job runs
-``-m faults`` under both notification backends with
-``REPRO_FAULTS_SOAK`` scaling the hypothesis example count.
+``-m faults`` with ``REPRO_FAULTS_SOAK`` scaling the hypothesis example
+count.
 """
 
 import os
@@ -263,12 +264,29 @@ def test_armed_injection_counts_only_the_victim():
 
 
 @pytest.mark.parametrize("mode", ["blocking", "event"])
-@pytest.mark.parametrize("notify", ["polling", "continuation"])
-def test_injected_death_surfaces_and_survivors_recover(mode, notify):
+@pytest.mark.parametrize("algorithm", ["ring", "doubling"])
+def test_injected_death_surfaces_and_survivors_recover(mode, algorithm):
     out = run_with_failure(n_ranks=4, victim=2, after_ops=1, mode=mode,
-                           notify=notify)
+                           algorithm=algorithm)
+    assert out.fired
     assert out.survivors.ranks == (0, 1, 3)
     assert 2 not in out.ok_ranks
+
+
+@pytest.mark.parametrize("after_ops,fired", [(2, True), (3, False)])
+def test_two_rank_doubling_death_point(after_ops, fired):
+    """A 2-rank doubling victim posts exactly two operations (one send,
+    one recv): a trap at its second op kills it; a trap at a third never
+    fires, and the run must then be clean — no failed rank, every
+    allreduce equal to numpy (checked inside the harness)."""
+    out = run_with_failure(n_ranks=2, victim=0, after_ops=after_ops,
+                           algorithm="doubling", mode="blocking")
+    assert out.fired is fired
+    if fired:
+        assert 0 not in out.ok_ranks
+        assert out.survivors.ranks == (1,)
+    else:
+        assert out.ok_ranks == [0, 1] and out.survivors is None
 
 
 def test_late_injection_lets_finished_ranks_through():

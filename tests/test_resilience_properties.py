@@ -2,9 +2,8 @@
 
 Samples the full cross-product the deterministic tests grid-spot-check:
 death point (operation count) × collective algorithm (ring / doubling /
-bruck / tree / hierarchical) × interoperability mode (blocking / event)
-× notification backend (polling / continuation), asserting through
-tests/fault_harness.py that every combination
+bruck / tree / hierarchical) × interoperability mode (blocking / event),
+asserting through tests/fault_harness.py that every combination
 
 * surfaces the injected death as ``RankFailedError`` without hanging the
   taskwait,
@@ -12,6 +11,10 @@ tests/fault_harness.py that every combination
 * completes the shrink agreement on exactly the survivors, and
 * converges the survivors' post-recovery allreduce to the numpy
   reference at the shrunken size.
+
+A death point past the victim's last posted operation never fires; the
+harness then holds the run to the clean result (no failed rank, every
+allreduce equal to numpy), and the survivor assertions are skipped.
 
 ``REPRO_FAULTS_SOAK=<n>`` raises the example count (the CI fault-soak
 job sets it); the default stays small so tier-1 wall time is bounded.
@@ -26,7 +29,7 @@ pytest.importorskip(
     reason="property sweep needs hypothesis (pip install -r "
            "requirements-dev.txt)")
 
-from hypothesis import given, settings, HealthCheck
+from hypothesis import assume, given, settings, HealthCheck
 import hypothesis.strategies as st
 
 from fault_harness import ALGORITHMS, run_with_failure
@@ -44,15 +47,14 @@ _SETTINGS = dict(deadline=None, max_examples=_SOAK or 10,
        after_ops=st.integers(min_value=1, max_value=6),
        algorithm=st.sampled_from(ALGORITHMS + ("hierarchical",)),
        mode=st.sampled_from(["blocking", "event"]),
-       notify=st.sampled_from(["polling", "continuation"]),
        seed=st.integers(min_value=0, max_value=2 ** 16))
 def test_property_any_failure_point_recovers(n_ranks, victim, after_ops,
-                                             algorithm, mode, notify, seed):
-    """For ANY death point in ANY algorithm under EITHER interop mode and
-    EITHER notification backend: the taskwait returns, teardown is
-    leak-free, the shrink agreement produces the survivor set, and the
-    survivors' post-recovery allreduce matches the numpy reference —
-    all asserted inside the harness."""
+                                             algorithm, mode, seed):
+    """For ANY death point in ANY algorithm under EITHER interop mode:
+    the taskwait returns, teardown is leak-free, the shrink agreement
+    produces the survivor set, and the survivors' post-recovery
+    allreduce matches the numpy reference — all asserted inside the
+    harness."""
     victim %= n_ranks
     hierarchical = None
     if algorithm == "hierarchical":
@@ -60,7 +62,7 @@ def test_property_any_failure_point_recovers(n_ranks, victim, after_ops,
         algorithm = "ring"
     out = run_with_failure(n_ranks=n_ranks, victim=victim,
                            after_ops=after_ops, algorithm=algorithm,
-                           hierarchical=hierarchical, mode=mode,
-                           notify=notify, seed=seed)
+                           hierarchical=hierarchical, mode=mode, seed=seed)
+    assume(out.fired)
     assert out.survivors.size == n_ranks - 1
     assert victim not in out.survivors.ranks

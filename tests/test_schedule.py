@@ -547,18 +547,35 @@ def test_calibrate_fit_recovers_constants(tmp_path):
     assert "MISSING" in run.stdout
 
 
-def test_calibrate_per_executor_overheads_and_gate(tmp_path):
-    """Rows labelled ``overhead_class`` fit per-family α/β/γ plus a
-    per-class overhead intercept; the gate hard-asserts the compiled
-    executor's intercept at ≤ 0.5× the interpreted one — and catches a
-    compiled-path regression even when no per-row ratio drifts."""
-    import json
-    import pathlib
-    import subprocess
-    import sys
-    # the default (XLA-leg) family, fitted exactly as before
-    true = {"alpha": 2e-6, "beta": 4e-9, "gamma": 1e-10, "overhead": 3e-3}
-    report = {"modes": {}, "level_a": {"compiled": {}, "interpreted": {}}}
+# the default (XLA-leg) family of the synthetic calibration bench
+_CAL_TRUE = {"alpha": 2e-6, "beta": 4e-9, "gamma": 1e-10, "overhead": 3e-3}
+# the level_a family: its OWN transport constants (host isend/irecv,
+# orders of magnitude off the XLA legs') + per-executor overheads
+_CAL_FAM = {"alpha": 1.2e-5, "beta": 2e-10, "gamma": 5e-11}
+
+
+def _level_a_rows(overheads):
+    configs = [("ring_small", 112, 7168, 3584),
+               ("ring_big", 112, 1835008, 917504),
+               ("dbl_small", 24, 12288, 12288),
+               ("dbl_big", 24, 3145728, 3145728)]
+    fam = _CAL_FAM
+    rows = {"compiled": {}, "interpreted": {}}
+    for executor, o in overheads.items():
+        for name, r, w, v in configs:
+            rows[executor][name] = {
+                "features": {"rounds": r, "wire_bytes": w,
+                             "combine_bytes": v},
+                "measured_s": (fam["alpha"] * r + fam["beta"] * w
+                               + fam["gamma"] * v + o),
+                "overhead_class": f"level_a:{executor}"}
+    return rows
+
+
+def _families_report():
+    """Exact synthetic bench rows for the default and level_a families."""
+    true = _CAL_TRUE
+    report = {"modes": {}}
     for i, (name, size) in enumerate(
             [("fused", 1 << 20), ("bucketed", 1 << 16),
              ("sentinel", 1 << 18), ("tiny", 1 << 8)]):
@@ -572,28 +589,22 @@ def test_calibrate_per_executor_overheads_and_gate(tmp_path):
                            + true["beta"] * feats["wire_bytes"]
                            + true["gamma"] * feats["combine_bytes"]
                            + true["overhead"])}
-    # the level_a family: its OWN transport constants (host isend/irecv,
-    # orders of magnitude off the XLA legs') + per-executor overheads
-    fam = {"alpha": 1.2e-5, "beta": 2e-10, "gamma": 5e-11}
-    configs = [("ring_small", 112, 7168, 3584),
-               ("ring_big", 112, 1835008, 917504),
-               ("dbl_small", 24, 12288, 12288),
-               ("dbl_big", 24, 3145728, 3145728)]
+    report["level_a"] = _level_a_rows({"compiled": 4e-5,
+                                       "interpreted": 2e-4})
+    return report
 
-    def level_a_rows(overheads):
-        rows = {"compiled": {}, "interpreted": {}}
-        for executor, o in overheads.items():
-            for name, r, w, v in configs:
-                rows[executor][name] = {
-                    "features": {"rounds": r, "wire_bytes": w,
-                                 "combine_bytes": v},
-                    "measured_s": (fam["alpha"] * r + fam["beta"] * w
-                                   + fam["gamma"] * v + o),
-                    "overhead_class": f"level_a:{executor}"}
-        return rows
 
-    report["level_a"] = level_a_rows({"compiled": 4e-5,
-                                      "interpreted": 2e-4})
+def test_calibrate_per_executor_overheads_and_gate(tmp_path):
+    """Rows labelled ``overhead_class`` fit per-family α/β/γ plus a
+    per-class overhead intercept; the gate hard-asserts the compiled
+    executor's intercept at ≤ 0.5× the interpreted one — and catches a
+    compiled-path regression even when no per-row ratio drifts."""
+    import json
+    import pathlib
+    import subprocess
+    import sys
+    true, fam = _CAL_TRUE, _CAL_FAM
+    report = _families_report()
     bench = tmp_path / "BENCH_overlap.json"
     bench.write_text(json.dumps(report))
     tool = pathlib.Path(__file__).resolve().parents[1] / "tools" / \
@@ -630,8 +641,8 @@ def test_calibrate_per_executor_overheads_and_gate(tmp_path):
     # compiled overhead regresses to 0.75× interpreted: the per-row
     # ratios barely move (well inside ×tolerance) but the executor
     # assertion fails the gate
-    report["level_a"] = level_a_rows({"compiled": 1.5e-4,
-                                      "interpreted": 2e-4})
+    report["level_a"] = _level_a_rows({"compiled": 1.5e-4,
+                                       "interpreted": 2e-4})
     bench.write_text(json.dumps(report))
     run = subprocess.run(
         [sys.executable, str(tool), "--bench", str(bench), "--gate",
@@ -811,10 +822,22 @@ def test_collectives_auto_with_hierarchy_runs_hierarchical():
         Collectives(tac.CommWorld(6), hierarchy=4)   # 4 does not divide 6
 
 
-def test_load_calibration_families():
-    consts = schedule_ir.load_calibration("CALIBRATION.json",
-                                          family="level_a")
+def test_load_calibration_families(tmp_path):
+    import json
+    import pathlib
+    import subprocess
+    import sys
+    bench = tmp_path / "BENCH_overlap.json"
+    bench.write_text(json.dumps(_families_report()))
+    tool = pathlib.Path(__file__).resolve().parents[1] / "tools" / \
+        "calibrate.py"
+    out = tmp_path / "CALIBRATION.json"
+    run = subprocess.run(
+        [sys.executable, str(tool), "--bench", str(bench), "--out",
+         str(out)], capture_output=True, text=True)
+    assert run.returncode == 0, run.stderr
+    consts = schedule_ir.load_calibration(out, family="level_a")
     assert set(consts) == {"alpha", "beta", "gamma"}
+    assert consts["alpha"] == pytest.approx(_CAL_FAM["alpha"], rel=1e-3)
     with pytest.raises(KeyError):
-        schedule_ir.load_calibration("CALIBRATION.json",
-                                     family="no-such-family")
+        schedule_ir.load_calibration(out, family="no-such-family")

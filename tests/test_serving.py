@@ -10,23 +10,22 @@ Covers the PR's acceptance surface:
    decode round, late admissions joining within ``ROUNDS_AHEAD`` rounds,
    and rows reused after finish and eviction.
 3. **Completion legs** — the event-bound and blocking-sentinel legs
-   emit bit-identical token streams across all four mode × notify
-   combinations, and the event leg (``tac.iwait`` binding device
-   completion into task dependencies) beats the blocking sentinel on
-   tokens/s AND p99 — the claim ``benchmarks/serve_bench.py`` gates in
-   CI.  Over the real model path (``LMAdapter`` at smoke width) a
-   round's tokens and logits equal the batch-1 decode's, row by row.
+   emit bit-identical token streams whether the device step returns a
+   push-capable future or a push-less device array, and the event leg
+   (``tac.iwait`` binding device completion into task dependencies)
+   beats the blocking sentinel on tokens/s AND p99 — the claim
+   ``benchmarks/serve_bench.py`` gates in CI.  Over the real model path
+   (``LMAdapter`` at smoke width) a round's tokens and logits equal the
+   batch-1 decode's, row by row.
 4. **Failure path** — a rank killed mid-serve surfaces through the
    stepwise taskwait, in-flight requests are evicted to the queue head,
    the world shrinks (ULFM revoke+shrink), and every request still
    finishes with its full, correct token stream.
 5. **Deprecation shims** — the pre-``CollectiveOptions`` keyword
-   spellings (``hierarchy=``, ``wire=``) and the retired ticket-pool
-   entry points warn but keep working.
+   spellings (``hierarchy=``, ``wire=``) warn but keep working.
 """
 
 import gc
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -297,22 +296,68 @@ def test_rows_reused_after_finish_and_eviction():
 # ---------------------------------------------------------------------------
 # 3. completion legs: parity and performance
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("notify", ["polling", "continuation"])
+class _ArrayTokens(SyntheticAdapter):
+    """The synthetic adapter returning each step's tokens as a device
+    array, as :class:`repro.serving.lm.LMAdapter` does on the chip: a
+    push-less handle the continuation engine re-tests from its fallback
+    poll list.  A jitted busy loop keeps the array in flight for a while
+    after dispatch, so the wait task usually finds it not yet ready."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self._busy = jnp.eye(128, dtype=jnp.float32)
+
+        def tokens(busy, seeds, steps):
+            busy = jax.lax.fori_loop(0, 64, lambda _, b: jnp.tanh(b @ b),
+                                     busy)
+            tok = (seeds + 31 * steps + 7) % 997
+            return jnp.where(jnp.isnan(busy[0, 0]), -1, tok)
+
+        self._tokens = jax.jit(tokens)
+
+    def warmup(self):
+        for n in (1, self.slots):
+            z = jnp.zeros((n,), jnp.int32)
+            self._tokens(self._busy, z, z).block_until_ready()
+
+    def prefill(self, req):
+        seed = int(req.prompt)
+        self._seeds[req.slot] = seed
+        return self._tokens(self._busy, jnp.array([seed], jnp.int32),
+                            jnp.zeros(1, jnp.int32))
+
+    def decode_round(self, row_steps):
+        steps = [0 if s is None else s for s in row_steps]
+        return self._tokens(self._busy, jnp.asarray(self._seeds),
+                            jnp.array(steps, jnp.int32))
+
+
+@pytest.mark.parametrize("tokens", ["future", "array"])
 @pytest.mark.parametrize("completion", ["event", "blocking"])
-def test_token_parity_mode_x_notify(completion, notify):
-    """All four completion × notification combinations produce the same
-    (deterministic) token streams through the real async adapter."""
-    with SyntheticAdapter(dev_ms=1.0, host_rounds=1) as ad:
+def test_token_parity_mode_x_tokens(completion, tokens):
+    """Both completion legs produce the same (deterministic) token
+    streams, whether the adapter's device step returns a push-capable
+    future or a push-less device array."""
+    adapter = {"future": SyntheticAdapter, "array": _ArrayTokens}[tokens]
+    with adapter(dev_ms=1.0, host_rounds=1) as ad:
         ad.warmup()
-        eng = ServingEngine(ad, slots=4, completion=completion,
-                            num_workers=3, notify=notify)
-        reqs = [Request(rid=i, prompt=30 + 7 * i, gen_len=4)
-                for i in range(6)]
-        rep = eng.run(reqs)
+        with TaskRuntime(num_workers=3) as rt:
+            eng = ServingEngine(ad, slots=4, completion=completion,
+                                runtime=rt)
+            reqs = [Request(rid=i, prompt=30 + 7 * i, gen_len=4)
+                    for i in range(6)]
+            rep = eng.run(reqs)
+            stats = rt.continuations.stats
     for r in reqs:
         assert rep.outputs[r.rid] == [token_at(r.prompt, s)
                                       for s in range(4)]
     assert rep.tokens == 24 and rep.recoveries == 0
+    if completion == "event":
+        assert stats["attached"] > 0
+        if tokens == "future":
+            assert stats["tests"] == 0      # every handle pushed
+        else:
+            assert stats["tests"] > 0       # rode the fallback poll list
 
 
 def test_event_leg_outperforms_blocking_sentinel():
@@ -496,7 +541,7 @@ def test_failed_step_does_not_finish_request():
 
 
 # ---------------------------------------------------------------------------
-# 5. deprecation shims (pre-CollectiveOptions spellings, ticket pool)
+# 5. deprecation shims (pre-CollectiveOptions spellings)
 # ---------------------------------------------------------------------------
 def test_collectives_hierarchy_kwarg_warns():
     w = tac.CommWorld(4)
@@ -549,20 +594,3 @@ def test_collective_options_spec():
     assert algorithm == "recursive"
     with pytest.raises(ValueError, match="not.*applicable"):
         CollectiveOptions(stage_wire="bf16").take(algorithm=None)
-
-
-def test_ticket_pool_shims_warn_and_delegate():
-    rt = TaskRuntime(num_workers=1)
-    try:
-        with pytest.warns(DeprecationWarning, match="ticket pool"):
-            pool = tac._TicketPool(rt)
-        h = tac.EventHandle()
-        with pytest.warns(DeprecationWarning, match="ticket pool"):
-            ticket = tac._Ticket(h)
-        assert pool.pending == rt.continuations.polled
-        with pytest.warns(DeprecationWarning, match="ticket pool"):
-            assert tac._use_continuations(rt) is True
-        with pytest.warns(DeprecationWarning, match="ticket pool"):
-            tac._pool(rt)
-    finally:
-        rt.close()

@@ -34,7 +34,7 @@ def test_array_handle_completion():
 
 
 def test_blocking_wait_inside_task_pauses():
-    """Fig. 3 path: incomplete handle → ticket → pause → poll → resume."""
+    """Fig. 3 path: incomplete handle → attach → pause → push → resume."""
     h = tac.EventHandle()
     got = []
 

@@ -7,10 +7,17 @@ row, ``H / Hkv`` times over in float32.  Here the queries are grouped as
 ``(B, S, Hkv, rep, D)`` and each contraction runs per KV head on the cache
 as stored, so it is read once per call.
 
-The arithmetic is the oracle's: q·k takes the bf16 operands with a float32
-result (their products are exact in float32), the softmax is float32, and
-p·v keeps the float32 probabilities at ``Precision.HIGHEST``.  Masks,
-window and valid length are the oracle's, position for position.
+The new positions' k and v come beside the cache, not written into it:
+the cache is read where it lies, at the positions before the new ones, and
+the step writes the new entries once, where it stores the cache.  The
+scores of the two parts are joined before one softmax, and the two p·v
+contractions are summed.
+
+The arithmetic is the oracle's over the cache with the new entries written
+at ``cache_index``: q·k takes the bf16 operands with a float32 result
+(their products are exact in float32), the softmax is float32, and p·v
+keeps the float32 probabilities at ``Precision.HIGHEST``.  Masks and
+window are the oracle's, position for position.
 """
 
 from __future__ import annotations
@@ -23,42 +30,46 @@ import jax.numpy as jnp
 from .ref import NEG_INF
 
 
-def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     k_new: jax.Array, v_new: jax.Array, *,
+                     cache_index: jax.Array,
                      causal: bool = True,
                      window: Optional[int] = None,
-                     kv_valid_len: Optional[jax.Array] = None,
-                     q_offset: Optional[jax.Array] = None,
                      scale: Optional[float] = None) -> jax.Array:
-    """q: (B, S, H, D); k, v: (B, T, Hkv, D) with H % Hkv == 0.
+    """q: (B, S, H, D); k, v: the cache, (B, T, Hkv, D) with H % Hkv == 0;
+    k_new, v_new: the S new positions', (B, S, Hkv, D).
 
+    ``cache_index``: (B,) — the absolute position of q[:, 0] and of the
+    first new entry; the cache attends only at positions before it.
     Scores are scaled by ``scale`` (default ``D^-1/2``).
-    ``kv_valid_len``: (B,) — only cache positions < len attend.
-    ``q_offset``: (B,) — absolute position of q[:, 0] (the cache index).
     """
     B, S, H, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     qg = q.reshape(B, S, Hkv, H // Hkv, D)
-    scores = jnp.einsum("bsgrd,btgd->bgrst", qg, k,
-                        preferred_element_type=jnp.float32)
-    if scale is None:
-        scores = scores / jnp.sqrt(D)
-    else:
-        scores = scores * scale
+    first = cache_index[:, None, None]                       # (B, 1, 1)
+    q_pos = jnp.arange(S)[None, :, None] + first             # (B, S, 1)
 
-    q_pos = jnp.arange(S)[None, :, None]                     # (1, S, 1)
-    if q_offset is not None:
-        q_pos = q_pos + q_offset[:, None, None]              # (B, S, 1)
-    k_pos = jnp.arange(T)[None, None, :]                     # (1, 1, T)
-    mask = jnp.ones((1, S, T), bool)
-    if causal:
-        mask = mask & (k_pos <= q_pos)
-    if window is not None:
-        mask = mask & (k_pos > q_pos - window)
-    if kv_valid_len is not None:
-        mask = mask & (k_pos < kv_valid_len[:, None, None])
-    scores = jnp.where(mask[:, None, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bgrst,btgd->bsgrd", probs, v,
-                     preferred_element_type=jnp.float32,
-                     precision=jax.lax.Precision.HIGHEST)
+    def scores(kp, k_pos, mask):
+        s = jnp.einsum("bsgrd,btgd->bgrst", qg, kp,
+                       preferred_element_type=jnp.float32)
+        s = s / jnp.sqrt(D) if scale is None else s * scale
+        if causal:
+            mask = mask & (k_pos <= q_pos)
+        if window is not None:
+            mask = mask & (k_pos > q_pos - window)
+        return jnp.where(mask[:, None, None], s, NEG_INF)
+
+    cache_pos = jnp.arange(T)[None, None, :]                 # (1, 1, T)
+    new_pos = jnp.arange(S)[None, None, :] + first           # (B, 1, S)
+    probs = jax.nn.softmax(jnp.concatenate(
+        [scores(k, cache_pos, cache_pos < first),
+         scores(k_new, new_pos, jnp.ones((1, 1, S), bool))], axis=-1),
+        axis=-1)
+
+    def pv(p, vp):
+        return jnp.einsum("bgrst,btgd->bsgrd", p, vp,
+                          preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
+
+    out = pv(probs[..., :T], v) + pv(probs[..., T:], v_new)
     return out.reshape(B, S, H, D).astype(q.dtype)

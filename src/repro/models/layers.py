@@ -4,7 +4,9 @@ Functional style: every block has ``<block>_init(key, cfg) -> params`` and
 ``<block>_apply(params, x, ...) -> (y, new_cache)``.  Params are plain
 pytrees (dicts of arrays) so sharding rules can be attached by path
 (runtime/sharding.py) and stacked along a leading layer dimension for
-``lax.scan`` over layers (models/model.py).
+``lax.scan`` over layers (models/model.py).  In decode an attention
+block's ``new_cache`` holds only its new k/v entries, written into the
+cache by the step; a recurrent block's is its whole new state.
 
 Numerics policy: weights and activations in ``cfg.dtype`` (bf16 by
 default); norms, SSM decay/bias terms and recurrent states in fp32.
@@ -114,15 +116,13 @@ def attn_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
 
     new_cache: Cache = None
     if cache is not None and cache_index is not None:
-        # decode: write new k/v at cache_index, attend over the cache
-        kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, cache_index, 0, 0))
-        vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, cache_index, 0, 0))
-        new_cache = {"k": kc, "v": vc}
-        valid = jnp.full((B,), cache_index + S, jnp.int32)
-        offs = jnp.full((B,), cache_index, jnp.int32)
-        o = ops.decode_attention(q, kc, vc, causal=cfg.causal,
-                                 window=window, kv_valid_len=valid,
-                                 q_offset=offs)
+        # decode: attend over the cache as stored plus the new k/v, and
+        # return only the new entries (the step writes them at cache_index)
+        o = ops.decode_attention(q, cache["k"], cache["v"], k, v,
+                                 cache_index=jnp.full((B,), cache_index,
+                                                      jnp.int32),
+                                 causal=cfg.causal, window=window)
+        new_cache = {"k": k, "v": v}
     else:
         o = ops.flash_attention(q, k, v, causal=cfg.causal, window=window,
                                 impl=cfg.kernel_impl, constrain=constrain)
@@ -566,9 +566,10 @@ def shared_block_apply(p: Params, a: Params, x: jax.Array, x0: jax.Array,
 
     ``kv`` is the KV cache of every invocation, (invocations, B, T, Hkv,
     dh), and ``slot`` this invocation's index in it: in decode
-    (``cache_index`` given) the new k/v are written at ``cache_index`` and
-    the block attends over its slot; in prefill the sequence's k/v fill
-    the slot.  Returns the output and the updated ``kv``.
+    (``cache_index`` given) the block attends over its slot as stored plus
+    the new k/v, and returns the new entries, (B, S, Hkv, dh) each, for
+    the step to write at ``cache_index``; in prefill the sequence's k/v
+    fill the slot, and the updated ``kv`` is returned.
     """
     B, S, _ = x.shape
     dh, H, Hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -581,16 +582,13 @@ def shared_block_apply(p: Params, a: Params, x: jax.Array, x0: jax.Array,
     if constrain is not None:
         q, k, v = constrain.heads(q), constrain.heads(k), constrain.heads(v)
     if cache_index is not None:
-        at = (slot, 0, cache_index, 0, 0)
-        kv = {"k": jax.lax.dynamic_update_slice(kv["k"], k[None], at),
-              "v": jax.lax.dynamic_update_slice(kv["v"], v[None], at)}
         kc, vc = (jax.lax.dynamic_index_in_dim(kv[n], slot, 0, False)
                   for n in ("k", "v"))
-        o = ops.decode_attention(q, kc, vc, causal=True, scale=scale,
-                                 kv_valid_len=jnp.full((B,), cache_index + S,
-                                                       jnp.int32),
-                                 q_offset=jnp.full((B,), cache_index,
-                                                   jnp.int32))
+        o = ops.decode_attention(q, kc, vc, k, v,
+                                 cache_index=jnp.full((B,), cache_index,
+                                                      jnp.int32),
+                                 causal=True, scale=scale)
+        kv = {"k": k, "v": v}
     else:
         o = ops.flash_attention(q, k, v, causal=True, scale=scale,
                                 impl=cfg.kernel_impl, constrain=constrain)

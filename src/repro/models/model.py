@@ -16,6 +16,11 @@ Three entry modes:
 * ``train``   — full sequence, logits for every position.
 * ``prefill`` — full sequence, returns the serving cache.
 * ``decode``  — one token against a fixed-size cache at ``cache_index``.
+
+Decode never rewrites a cache inside the layer stack: the stack reads every
+KV cache where it lies and returns each attention layer's new entries
+(:func:`decode_entries`), and one write puts them in place
+(:func:`write_entries`).  Recurrent state comes back whole.
 """
 
 from __future__ import annotations
@@ -204,8 +209,11 @@ def _hybrid_stack(params, x, cfg: ModelConfig, *, mode: str, cache,
         t = shared(x, x0) if hybrid else 0
         x = x + mamba2(rmsnorm(x + t))
 
-    The invocations' KV caches, one leaf stacked over invocations
-    (``SHARED_KV``), ride in the scan's carry.
+    The invocations' KV caches are one leaf stacked over invocations
+    (``SHARED_KV``).  Prefill fills them in the scan's carry.  Decode
+    reads them where they lie: every layer yields its invocation's new
+    entries (zeros where it has none), and the invocations' layers are
+    gathered after the scan.
     """
     (kind,), = cfg.pattern
     if kind != MAMBA2:
@@ -215,16 +223,16 @@ def _hybrid_stack(params, x, cfg: ModelConfig, *, mode: str, cache,
     invocation = np.full(cfg.n_layers, -1, np.int32)
     invocation[list(cfg.hybrid_layers)] = np.arange(len(cfg.hybrid_layers))
     shared, x0 = params["shared"], x
-    if mode == "decode":
+    decoding = mode == "decode"
+    B, S = x.shape[:2]
+    kv = None
+    if decoding:
         kv = cache[SHARED_KV]
     elif mode == "prefill":
-        B, S = x.shape[:2]
         kv = jax.tree_util.tree_map(
             lambda a: jnp.zeros((len(cfg.hybrid_layers),) + a.shape,
                                 a.dtype),
             jax.eval_shape(lambda: L.attn_cache_init(cfg, B, S)))
-    else:
-        kv = None
 
     def invoke(i, xc, kv):
         with jax.named_scope("zamba2/shared_block"):
@@ -237,26 +245,35 @@ def _hybrid_stack(params, x, cfg: ModelConfig, *, mode: str, cache,
                 cache_index=cache_index, constrain=constrain)
 
     def skip(i, xc, kv):
+        if decoding:  # no invocation here: no new entries
+            kv = L.attn_cache_init(cfg, B, S)
         return jnp.zeros_like(xc), kv
 
     def body(carry, xs):
-        xc, kv = carry
+        xc, kv_carry = carry
         layer_p, layer_cache, i = xs
-        t, kv = jax.lax.cond(i >= 0, invoke, skip, i, xc, kv)
+        t, kv_out = jax.lax.cond(i >= 0, invoke, skip, i, xc,
+                                 kv if decoding else kv_carry)
         with jax.named_scope("zamba2/mamba2"):
             h, nc = L.mamba2_apply(
                 layer_p[name], L.norm_apply(layer_p[norm], xc + t, cfg), cfg,
                 cache=layer_cache.get(name), make_cache=mode == "prefill",
                 constrain=constrain)
         out = {name: nc} if mode != "train" else {}
-        return (constrain.residual(xc + h), kv), out
+        if decoding:
+            out[SHARED_KV], kv_out = kv_out, None
+        return (constrain.residual(xc + h), kv_out), out
 
-    layer_cache = {name: cache[name]} if mode == "decode" else {}
-    (x, kv), new_cache = jax.lax.scan(
-        _remat(body, remat), (x, kv),
+    layer_cache = {name: cache[name]} if decoding else {}
+    (x, kv_carry), new_cache = jax.lax.scan(
+        _remat(body, remat), (x, None if decoding else kv),
         (params["layers"], layer_cache, jnp.asarray(invocation)))
-    if mode != "train":
-        new_cache[SHARED_KV] = kv
+    if decoding:
+        at = np.asarray(cfg.hybrid_layers)
+        new_cache[SHARED_KV] = jax.tree_util.tree_map(
+            lambda e: e[at], new_cache[SHARED_KV])
+    elif mode == "prefill":
+        new_cache[SHARED_KV] = kv_carry
     return x, new_cache
 
 
@@ -264,7 +281,47 @@ def apply(params: Dict[str, Any], cfg: ModelConfig, batch: Dict[str, Any], *,
           mode: str = "train", cache=None, cache_index=None,
           constrain=None, remat: Optional[str] = None
           ) -> Tuple[jax.Array, Any, jax.Array]:
-    """Forward pass.  Returns (logits, new_cache, aux_loss)."""
+    """Forward pass.  Returns (logits, new_cache, aux_loss).
+
+    Decode is :func:`decode_entries`, then :func:`write_entries` at
+    ``cache_index``.
+    """
+    logits, new_cache, aux = _forward(
+        params, cfg, batch, mode=mode, cache=cache, cache_index=cache_index,
+        constrain=constrain, remat=remat)
+    if mode == "decode":
+        new_cache = write_entries(cache, new_cache, cache_index)
+    return logits, new_cache, aux
+
+
+def decode_entries(params: Dict[str, Any], cfg: ModelConfig,
+                   batch: Dict[str, Any], *, cache, cache_index,
+                   constrain=None) -> Tuple[jax.Array, Any]:
+    """One decode step that leaves ``cache`` as it is.  Returns (logits,
+    entries): ``entries`` has the cache's tree; a KV leaf holds the step's
+    new entries, (layers, B, S, Hkv, dh), for positions ``cache_index``
+    onwards, and a recurrent leaf the whole new state."""
+    logits, entries, _ = _forward(params, cfg, batch, mode="decode",
+                                  cache=cache, cache_index=cache_index,
+                                  constrain=constrain)
+    return logits, entries
+
+
+def write_entries(cache, entries, cache_index):
+    """The cache after a decode step: each KV leaf with its new entries
+    written in place at ``cache_index``, each recurrent leaf replaced."""
+    def write(path, c, e):
+        if is_kv(path):  # (layers, B, T, Hkv, dh)
+            return jax.lax.dynamic_update_slice_in_dim(c, e, cache_index, 2)
+        return e
+    return jax.tree_util.tree_map_with_path(write, cache, entries)
+
+
+def _forward(params, cfg: ModelConfig, batch, *, mode: str, cache,
+             cache_index, constrain, remat: Optional[str] = None):
+    """The layer stack and the head.  Returns (logits, new_cache, aux);
+    in decode ``new_cache`` is the step's entries (:func:`decode_entries`).
+    """
     constrain = constrain or _NoConstrain()
 
     if cfg.frontend == "audio":

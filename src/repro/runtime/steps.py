@@ -244,8 +244,12 @@ def build_round_step(cfg: ModelConfig, mesh, policy: ShardingPolicy, *,
     arithmetic (per-row position, MoE capacity, recurrent state).  The
     rows stack on axis 1 of every cache leaf, right after the
     layer-repeat axis the model scans over: where the scan's batching
-    keeps a mapped axis, so no axis of the whole cache is moved.
-    Returns ``(decode, handoff, abstract_cache)``:
+    keeps a mapped axis, so no axis of the whole cache is moved.  The
+    mapped step reads the cache as it lies and returns each row's new KV
+    entries (:func:`repro.models.model.decode_entries`); the round then
+    writes them in place, one row at a time at scalar indices, outside the
+    map (mapped, the per-row write would be a scatter that moves the whole
+    cache).  Returns ``(decode, handoff, abstract_cache)``:
 
     * ``decode(params, cache, toks, positions) -> (toks, logits, cache)``
       feeds row r its token ``toks[r]`` at ``positions[r]`` and returns
@@ -258,15 +262,25 @@ def build_round_step(cfg: ModelConfig, mesh, policy: ShardingPolicy, *,
     constrain = Constrainer(mesh, policy, decode=True)
 
     def row_step(params, cache, tok, pos):
-        logits, cache, _ = model.apply(
-            params, cfg, {"tokens": tok.reshape(1, 1)}, mode="decode",
-            cache=cache, cache_index=pos, constrain=constrain)
-        return logits[0, -1], cache
+        logits, entries = model.decode_entries(
+            params, cfg, {"tokens": tok.reshape(1, 1)}, cache=cache,
+            cache_index=pos, constrain=constrain)
+        return logits[0, -1], entries
 
     def decode(params, cache, toks, positions):
-        logits, cache = jax.vmap(row_step, in_axes=(None, 1, 0, 0),
-                                 out_axes=(0, 1))(params, cache, toks,
-                                                  positions)
+        logits, entries = jax.vmap(row_step, in_axes=(None, 1, 0, 0),
+                                   out_axes=(0, 1))(params, cache, toks,
+                                                    positions)
+
+        def write(path, c, e):
+            if not model.is_kv(path):
+                return e            # recurrent state, whole from the round
+            for r in range(slots):  # (layers, slots, 1, T, Hkv, dh)
+                c = jax.lax.dynamic_update_slice(
+                    c, e[:, r:r + 1], (0, r, 0, positions[r], 0, 0))
+            return c
+
+        cache = jax.tree_util.tree_map_with_path(write, cache, entries)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
 
     def handoff(cache, toks, new, logits, row):

@@ -63,7 +63,8 @@ def test_flash_attention_blocks_sweep():
 # grouped-query decode attention
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("vmapped", [False, True])
-@pytest.mark.parametrize("mask", ["full", "window", "partial"])
+@pytest.mark.parametrize("mask", ["full", "window", "partial",
+                                  "full-S2", "window-S2", "partial-S2"])
 @pytest.mark.parametrize("H,Hkv,D,scale", [
     (32, 8, 64, None),                 # granite
     (32, 32, 160, (160 / 2) ** -0.5),  # zamba2's shared block
@@ -71,41 +72,56 @@ def test_flash_attention_blocks_sweep():
     (48, 8, 128, None),                # mixtral
 ])
 def test_decode_attention_vs_ref(H, Hkv, D, scale, mask, vmapped):
-    """One new position over a bf16 cache, as ``attn_apply`` decodes it:
-    the grouped contraction gives the oracle's float32 result, alone and
-    mapped over 4 rows at different positions as the decode round runs
-    it.  ``full``: the last cache position (valid length T); ``window``:
-    a sliding window; ``partial``: valid length below T."""
+    """New positions over a bf16 cache, as ``attn_apply`` decodes them:
+    their k/v come beside the cache, whose own entries there are stale,
+    and the grouped contraction gives the oracle's float32 result over the
+    cache with them written in place, alone and mapped over 4 rows at
+    different positions as the decode round runs it.  ``full``: the last
+    cache positions; ``window``: a sliding window; ``partial``: positions
+    below T, one row at position 0.  ``-S2``: two new positions, causal
+    among themselves; else one."""
     T, rows = 128, 4
-    window = 16 if mask == "window" else None
-    pos = {"full": [T - 1] * rows, "window": [100, 37, 127, 5],
-           "partial": [60, 3, 99, 0]}[mask]
-    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    kind, _, s = mask.partition("-")
+    S = int(s[1:]) if s else 1
+    window = 16 if kind == "window" else None
+    pos = [min(p, T - S) for p in {"full": [T - 1] * rows,
+                                   "window": [100, 37, 127, 5],
+                                   "partial": [60, 3, 99, 0]}[kind]]
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
     # q holds bf16 values in float32, so that both sides return float32
-    q = jax.random.normal(ks[0], (rows, 1, 1, H, D), jnp.bfloat16)
+    q = jax.random.normal(ks[0], (rows, 1, S, H, D), jnp.bfloat16)
     k = jax.random.normal(ks[1], (rows, 1, T, Hkv, D), jnp.bfloat16)
     v = jax.random.normal(ks[2], (rows, 1, T, Hkv, D), jnp.bfloat16)
+    k_new = jax.random.normal(ks[3], (rows, 1, S, Hkv, D), jnp.bfloat16)
+    v_new = jax.random.normal(ks[4], (rows, 1, S, Hkv, D), jnp.bfloat16)
 
-    def row(fn, q, k, v, p):
-        return fn(q, k, v, causal=True, window=window, scale=scale,
-                  kv_valid_len=jnp.full((1,), p + 1, jnp.int32),
-                  q_offset=jnp.full((1,), p, jnp.int32))
+    def decode(q, k, v, kn, vn, p):
+        return ops.decode_attention(q, k, v, kn, vn,
+                                    cache_index=jnp.full((1,), p, jnp.int32),
+                                    causal=True, window=window, scale=scale)
+
+    def oracle(q, k, v, kn, vn, p):
+        k = jax.lax.dynamic_update_slice(k, kn, (0, p, 0, 0))
+        v = jax.lax.dynamic_update_slice(v, vn, (0, p, 0, 0))
+        return ref.attention(q, k, v, causal=True, window=window,
+                             scale=scale,
+                             kv_valid_len=jnp.full((1,), p + S, jnp.int32),
+                             q_offset=jnp.full((1,), p, jnp.int32))
 
     def run(fn, q):
+        args = (q, k, v, k_new, v_new, jnp.asarray(pos))
         if vmapped:
-            return jax.vmap(lambda *a: row(fn, *a))(q, k, v,
-                                                    jnp.asarray(pos))
-        return jnp.stack([row(fn, q[r], k[r], v[r], pos[r])
-                          for r in range(rows)])
+            return jax.vmap(fn)(*args)
+        return jnp.stack([fn(*(a[r] for a in args)) for r in range(rows)])
 
-    out = run(ops.decode_attention, q.astype(jnp.float32))
-    exp = run(ref.attention, q.astype(jnp.float32))
+    out = run(decode, q.astype(jnp.float32))
+    exp = run(oracle, q.astype(jnp.float32))
     assert out.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=1e-5, atol=1e-6)
     # bf16 queries, as the model passes them: a bf16 result within one
     # rounding of the oracle's
-    out16 = run(ops.decode_attention, q)
+    out16 = run(decode, q)
     assert out16.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out16, np.float32),
                                np.asarray(exp), rtol=2 ** -8, atol=1e-6)

@@ -35,9 +35,9 @@ def _setup(arch):
 
 
 def batch1_greedy(cfg, params, mesh, policy):
-    """``greedy(prompt, n)``: tokens and logits of ``n`` greedy steps,
-    batch 1 — prefill, the cache padded to the decode length, then one
-    decode per step."""
+    """``greedy(prompt, n)``: tokens and logits of ``n`` greedy steps, and
+    the cache after them, batch 1 — prefill, the cache padded to the
+    decode length, then one decode per step."""
     with mesh:
         prefill = steps.build_prefill_step(cfg, mesh, policy)
         decode, _ = steps.build_decode_step(
@@ -57,16 +57,17 @@ def batch1_greedy(cfg, params, mesh, policy):
                 tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 toks.append(int(tok[0]))
                 lgs.append(np.asarray(logits[0, -1], np.float32))
-        return toks, lgs
+        return toks, lgs, cache
     return greedy
 
 
-@pytest.mark.parametrize("arch", ["granite-3-2b", "mixtral-8x22b",
-                                  "zamba2-2.7b", "xlstm-350m"])
-def test_round_matches_batch1_decode(arch):
-    """Three requests prefilled into rows 0, 2 and 3 at different
-    rounds, row 1 empty: every round's tokens equal each request's
-    batch-1 greedy tokens, and its logits agree within bf16 rounding."""
+ARCHS = ["granite-3-2b", "mixtral-8x22b", "zamba2-2.7b", "xlstm-350m"]
+
+
+def _rounds(arch):
+    """Three requests prefilled into rows 0, 2 and 3 at different rounds,
+    row 1 empty, three rounds.  Returns the setup, the adapter and, by
+    row, [request, rounds decoded, tokens, logits]."""
     cfg, params, mesh, policy = _setup(arch)
     ad = LMAdapter(cfg, mesh, policy, params, prompt_len=PROMPT,
                    gen_len=GEN, slots=4)
@@ -93,9 +94,18 @@ def test_round_matches_batch1_decode(arch):
         if late:
             admit(*late)
     assert sorted(rows) == [0, 2, 3]
-    greedy = batch1_greedy(cfg, params, mesh, policy)
+    return (cfg, params, mesh, policy), ad, rows
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_round_matches_batch1_decode(arch):
+    """Three requests prefilled into rows 0, 2 and 3 at different
+    rounds, row 1 empty: every round's tokens equal each request's
+    batch-1 greedy tokens, and its logits agree within bf16 rounding."""
+    setup, ad, rows = _rounds(arch)
+    greedy = batch1_greedy(*setup)
     for row, (req, _, got, got_logits) in rows.items():
-        want, want_logits = greedy(ad.prompt(req), len(got))
+        want, want_logits, _ = greedy(ad.prompt(req), len(got))
         assert got == want, (arch, row)
         for step in range(1, len(got)):
             w = want_logits[step]
@@ -103,6 +113,34 @@ def test_round_matches_batch1_decode(arch):
                 got_logits[step], w, rtol=0,
                 atol=2 * float(jnp.finfo(jnp.bfloat16).eps)
                 * float(np.max(np.abs(w))))
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_round_cache_matches_batch1_cache(arch):
+    """After the rounds of ``test_round_matches_batch1_decode``, each
+    occupied row of the stacked cache equals its request's batch-1 decode
+    cache, leaf by leaf, within the rounding the logits are held to: the
+    positions each round wrote, and the handoff's padding where no round
+    wrote.  The empty row's KV holds only what its position-0 decodes
+    wrote.  A write at the wrong row or position shows here even where the
+    tokens agree."""
+    setup, ad, rows = _rounds(arch)
+    greedy = batch1_greedy(*setup)
+    leaves = jax.tree_util.tree_flatten_with_path(ad._cache)[0]
+    for row, (req, _, got, _) in rows.items():
+        _, _, want = greedy(ad.prompt(req), len(got))
+        for (path, c), w in zip(leaves, jax.tree_util.tree_leaves(want)):
+            w = np.asarray(w, np.float32)
+            np.testing.assert_allclose(
+                np.asarray(c[:, row], np.float32), w, rtol=0,
+                atol=2 * float(jnp.finfo(jnp.bfloat16).eps)
+                * float(np.max(np.abs(w))),
+                err_msg=f"{arch} row {row} {jax.tree_util.keystr(path)}")
+    for path, c in leaves:
+        if model.is_kv(path):       # (layers, slots, 1, T, Hkv, dh)
+            empty = np.asarray(c[:, 1], np.float32)
+            assert np.all(empty[:, :, 1:] == 0), jax.tree_util.keystr(path)
+            assert np.any(empty[:, :, 0] != 0), jax.tree_util.keystr(path)
 
 
 def test_engine_over_lm_adapter_matches_batch1():
@@ -127,8 +165,8 @@ def test_engine_over_lm_adapter_matches_batch1():
     assert outputs["event"] == outputs["blocking"]
     greedy = batch1_greedy(cfg, params, mesh, policy)
     for i, g in enumerate(gens):
-        want, _ = greedy(ad.prompt(Request(rid=i, prompt=200 + i,
-                                           gen_len=g)), g)
+        want, _, _ = greedy(ad.prompt(Request(rid=i, prompt=200 + i,
+                                              gen_len=g)), g)
         assert outputs["event"][i] == want, i
 
 

@@ -5,24 +5,33 @@ described (not attached) ``v5e:2x2`` topology, so the TPU compiler refuses
 here what it would refuse on the chip: block shapes that break the
 tiling rule, stores it cannot lower, more VMEM than a kernel may use.
 The interpret-mode tests in ``test_kernels.py`` cannot see any of that.
+One test compiles granite-3-2b's decode round the same way and reads
+what the compiler makes of its cache.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process may load the TPU library, and the test
 workers all import this file.  Keep these tests in this one file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
+from repro import configs
 from repro.kernels import collective_stages as stages
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba2_ssd import mamba2_ssd
 from repro.kernels.mlstm_chunk import mlstm_chunk
 from repro.kernels.moe_gmm import moe_gmm
+from repro.runtime import steps
+from repro.runtime.sharding import ShardingPolicy
 
 
 @pytest.fixture(scope="module")
@@ -94,6 +103,39 @@ def test_moe_gmm_olmoe(one_chip):
     # olmoe-1b-7b: 64 experts, d_model 2048, expert d_ff 1024.
     _compile(moe_gmm, one_chip, ((64, 128, 2048), BF16),
              ((64, 2048, 1024), BF16))
+
+
+def test_decode_round_writes_cache_in_place(topo):
+    """granite-3-2b's round at full width (4 slots, cache 2048, the Pallas
+    prefill kernels) reads each KV cache where it lies and writes only the
+    new positions: no temporaries of a cache's size, and no ``copy`` of a
+    whole stacked KV leaf, (40, 4, 1, 2048, 8, 64) in any order."""
+    cfg = dataclasses.replace(configs.get("granite-3-2b"),
+                              kernel_impl="pallas")
+    slots, T = 4, 2048
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    one = NamedSharding(mesh, PartitionSpec())
+    policy = ShardingPolicy(fsdp=False, tp=False, sp=False, remat=None)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    with mesh:
+        decode, _, a_cache = steps.build_round_step(
+            cfg, mesh, policy, slots=slots, cache_len=T)
+        rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        compiled = decode.lower(shaped(steps.abstract_params(cfg)),
+                                shaped(a_cache), rows, rows).compile()
+    kv_dims = sorted(a_cache["L0_0_attn"]["k"].shape)
+    assert kv_dims == sorted((cfg.repeats, slots, 1, T, cfg.n_kv_heads,
+                              cfg.head_dim))
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+    copies = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(",
+                                          compiled.as_text())
+              if sorted(int(d) for d in dims.split(",")) == kv_dims]
+    assert not copies, copies
 
 
 def test_fused_combine(one_chip):
